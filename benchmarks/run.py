@@ -32,21 +32,20 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     ap.add_argument("--tier", default="host", choices=["host", "device"],
                     help="host (default): the CPU-oracle suite. device: "
-                         "re-run the kernel-facing benchmarks on the real "
-                         "accelerator backend — with no TPU/GPU present "
-                         "this SKIPS CLEANLY (exit 0), so the CI job is a "
-                         "no-op off-accelerator")
+                         "re-run the kernel-facing benchmarks on the TPU; "
+                         "fails when JAX finds no TPU")
     args, _ = ap.parse_known_args()
     quick = not args.full
     smoke = args.smoke
 
+    from repro.utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.tier == "device":
         import jax
         backend = jax.default_backend()
-        if backend not in ("tpu", "gpu"):
-            print(f"tier=device: no accelerator backend "
-                  f"(jax.default_backend()={backend!r}) — skipping cleanly")
-            raise SystemExit(0)
+        if backend != "tpu":
+            raise SystemExit(f"tier=device needs a TPU; JAX found "
+                             f"{backend!r}")
 
     from benchmarks import (fl_paper, theory_table, kernel_bench,
                             roofline_table, ablation_reweight,

@@ -42,29 +42,40 @@ import jax.numpy as jnp
 def pack_codes(codes, bits: int):
     """(..., C) uint8 codes (< 2**bits) -> (..., C*bits/8) packed uint8.
 
-    C must divide by 8//bits; the flat-buffer lane padding (multiples of
-    the 128-lane kernel tile) guarantees that for bits in {2, 4, 8}."""
+    Group-planar layout (``kernels.luq.pack_group``): each group of G codes
+    packs into G/k bytes (k = 8//bits), byte j holding codes j, j + G/k,
+    ..., j + (k-1)*G/k of the group, LSB-first. G is 128*k where C allows,
+    else C. C must divide by k; the flat-buffer lane padding (multiples of
+    the 2048-lane kernel tile) guarantees both for bits in {2, 4, 8}."""
+    from repro.kernels.luq import pack_group    # lazy: no import cycle
     k = 8 // bits
     if k == 1:
         return codes.astype(jnp.uint8)
-    if codes.shape[-1] % k:
-        raise ValueError(f"cannot pack {codes.shape[-1]} codes into "
+    C = codes.shape[-1]
+    if C % k:
+        raise ValueError(f"cannot pack {C} codes into "
                          f"{bits}-bit groups of {k}")
-    parts = codes.reshape(codes.shape[:-1] + (-1, k)).astype(jnp.uint8)
-    out = parts[..., 0]
+    g = pack_group(C, bits)
+    planes = codes.reshape(codes.shape[:-1] + (C // g, k, g // k))
+    planes = planes.astype(jnp.uint8)
+    out = planes[..., 0, :]
     for i in range(1, k):
-        out = out | (parts[..., i] << jnp.uint8(i * bits))
-    return out
+        out = out | (planes[..., i, :] << jnp.uint8(i * bits))
+    return out.reshape(codes.shape[:-1] + (C // k,))
 
 
 def unpack_codes(packed, bits: int):
     """Inverse of :func:`pack_codes`: (..., P) uint8 -> (..., P*8/bits)."""
+    from repro.kernels.luq import pack_group    # lazy: no import cycle
     k = 8 // bits
     if k == 1:
         return packed
+    P = packed.shape[-1]
+    g = pack_group(P * k, bits)
+    groups = packed.reshape(packed.shape[:-1] + (P * k // g, g // k))
     mask = jnp.uint8((1 << bits) - 1)
-    cols = [(packed >> jnp.uint8(i * bits)) & mask for i in range(k)]
-    return jnp.stack(cols, axis=-1).reshape(packed.shape[:-1] + (-1,))
+    planes = [(groups >> jnp.uint8(i * bits)) & mask for i in range(k)]
+    return jnp.stack(planes, axis=-2).reshape(packed.shape[:-1] + (P * k,))
 
 
 # ---------------------------------------------------------------------------

@@ -503,25 +503,26 @@ def _constrain_cold(spec: FlatSpec, mesh, cold) -> tuple:
     return tuple(out)
 
 
-def fused_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
-                        alpha_p, mask_p, s: float, *, progress_b=None,
-                        progress_codes_b=None, progress_bits: int = 0,
-                        n_logical: Optional[int] = None, mesh=None,
-                        use_kernel: Optional[bool] = None):
-    """One bucket's fused aggregation + selected-client reset, mesh-aware.
+def _bucket_dispatch(flat_fn, spec: FlatSpec, b: int, server_b, trained_b,
+                     inits_b, alpha_p, mask_p, s: float, *, progress_b,
+                     progress_codes_b, progress_bits: int, n_logical, mesh,
+                     use_kernel, stacked_outputs: bool):
+    """The mesh-aware dispatch shared by :func:`fused_bucket_update` and
+    :func:`stream_bucket_update` (docs/architecture.md §6). ``flat_fn`` is
+    ``favas_fused_flat`` or ``favas_stream_flat``; ``stacked_outputs`` says
+    whether it also returns the (n, Dp) client/init stacks.
 
-    Dispatch (docs/architecture.md §6):
-
-    * no mesh, or a replicated bucket -> plain ``favas_fused_flat`` (kernel
-      or oracle; GSPMD replicates it on a mesh);
-    * sharded bucket + kernel -> ``shard_map`` over the model axis: each
-      device runs the Pallas kernel on its own (n, Dp_b/S) flat slice. The
-      slice is lane-tile aligned by construction (per-shard padding), the
-      client reduction is shard-local, and the body contains no collectives
-      — the round cannot all-gather the buffer;
-    * sharded bucket + oracle -> the jnp expression under pjit with explicit
-      output ``PartitionSpec`` constraints; GSPMD partitions the elementwise
-      lanes and the (unsharded) client-axis reduction locally.
+    * no mesh -> a plain ``flat_fn`` call (kernel or oracle);
+    * kernel on a mesh -> ``shard_map``: each device runs the Pallas kernel
+      on its own (n, Dp_b/S) lane slice of a model-sharded bucket, or on
+      the whole bucket when it is replicated (the chip's compiler cannot
+      partition a Pallas call, so the kernel never runs unwrapped on a
+      mesh). Slices are lane-tile aligned by construction (per-shard
+      padding), the client reduction is shard-local, and the body contains
+      no collectives — the round cannot all-gather the buffer;
+    * oracle on a mesh -> the jnp expression; a sharded bucket gets
+      explicit output ``PartitionSpec`` constraints so GSPMD partitions
+      the elementwise lanes and the (unsharded) client reduction locally.
 
     ``progress_codes_b`` (mutually exclusive with ``progress_b``): the
     transmitted progress as a ``{"codes", "scale"}`` encoding from
@@ -529,28 +530,25 @@ def fused_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
     ``shards=spec.shards(b)``. The per-shard scale layout makes the codes-in
     shard_map body exactly per-device: each device's codes slice is a
     standalone shards=1 encoding of its own lane segment, so the kernel
-    dequantizes shard-locally with no collectives.
-
-    Returns (server_new, clients_new, inits_new) with the inputs' shardings.
-    """
+    dequantizes shard-locally with no collectives."""
     if progress_b is not None and progress_codes_b is not None:
         raise ValueError("progress_b and progress_codes_b are mutually "
                          "exclusive")
-    if mesh is None or spec.shards(b) <= 1:
-        return favas_fused_flat(server_b, trained_b, inits_b, alpha_p, mask_p,
-                                float(s), progress=progress_b,
-                                progress_codes=progress_codes_b,
-                                progress_bits=progress_bits,
-                                progress_shards=max(1, spec.shards(b)),
-                                client_tile=spec.client_tile,
-                                n_logical=n_logical, use_kernel=use_kernel)
+    common = dict(progress_bits=progress_bits, client_tile=spec.client_tile,
+                  n_logical=n_logical)
+    if mesh is None:
+        return flat_fn(server_b, trained_b, inits_b, alpha_p, mask_p,
+                       float(s), progress=progress_b,
+                       progress_codes=progress_codes_b,
+                       progress_shards=max(1, spec.shards(b)),
+                       use_kernel=use_kernel, **common)
     kernel_active = (use_kernel if use_kernel is not None
                      else jax.default_backend() == "tpu")
-    from jax.sharding import PartitionSpec as P
-    lane, row, vec = P(spec.mesh_axis), P(None, spec.mesh_axis), P(None)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    ax = spec.mesh_axis if spec.shards(b) > 1 else None
+    lane, row, vec = P(ax), P(None, ax), P(None)
+    out_specs = (lane, row, row) if stacked_outputs else lane
     if kernel_active:
-        from jax.experimental.shard_map import shard_map
-
         def body(*ops):
             pr = pc = None
             if progress_b is not None:
@@ -561,13 +559,10 @@ def fused_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
             else:
                 srv, cli, ini, al, mk = ops
             # per-device view: the local codes slice is one shard segment
-            # with its own (rows, 1) scale column -> progress_shards=1
-            return favas_fused_flat(srv, cli, ini, al, mk, float(s),
-                                    progress=pr, progress_codes=pc,
-                                    progress_bits=progress_bits,
-                                    progress_shards=1,
-                                    client_tile=spec.client_tile,
-                                    n_logical=n_logical, use_kernel=True)
+            # with its own scale column -> progress_shards=1
+            return flat_fn(srv, cli, ini, al, mk, float(s), progress=pr,
+                           progress_codes=pc, progress_shards=1,
+                           use_kernel=True, **common)
 
         operands = [server_b, trained_b, inits_b]
         in_specs = [lane, row, row]
@@ -581,19 +576,31 @@ def fused_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
             in_specs += [row, row]
         operands += [alpha_p, mask_p]
         in_specs += [vec, vec]
-        return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                         out_specs=(lane, row, row),
-                         check_rep=False)(*operands)
-    from jax.sharding import NamedSharding
-    out = favas_fused_flat(server_b, trained_b, inits_b, alpha_p, mask_p,
-                           float(s), progress=progress_b,
-                           progress_codes=progress_codes_b,
-                           progress_bits=progress_bits,
-                           progress_shards=spec.shards(b),
-                           client_tile=spec.client_tile,
-                           n_logical=n_logical, use_kernel=False)
-    return tuple(jax.lax.with_sharding_constraint(o, NamedSharding(mesh, p))
-                 for o, p in zip(out, (lane, row, row)))
+        return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                             out_specs=out_specs, check_vma=False)(*operands)
+    out = flat_fn(server_b, trained_b, inits_b, alpha_p, mask_p, float(s),
+                  progress=progress_b, progress_codes=progress_codes_b,
+                  progress_shards=spec.shards(b), use_kernel=False, **common)
+    if ax is None:
+        return out
+    return jax.tree_util.tree_map(
+        lambda o, p: jax.lax.with_sharding_constraint(o, NamedSharding(mesh, p)),
+        out, out_specs, is_leaf=lambda x: isinstance(x, P))
+
+
+def fused_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
+                        alpha_p, mask_p, s: float, *, progress_b=None,
+                        progress_codes_b=None, progress_bits: int = 0,
+                        n_logical: Optional[int] = None, mesh=None,
+                        use_kernel: Optional[bool] = None):
+    """One bucket's fused aggregation + selected-client reset, mesh-aware
+    (dispatch: :func:`_bucket_dispatch`). Returns (server_new, clients_new,
+    inits_new) with the inputs' shardings."""
+    return _bucket_dispatch(
+        favas_fused_flat, spec, b, server_b, trained_b, inits_b, alpha_p,
+        mask_p, s, progress_b=progress_b, progress_codes_b=progress_codes_b,
+        progress_bits=progress_bits, n_logical=n_logical, mesh=mesh,
+        use_kernel=use_kernel, stacked_outputs=True)
 
 
 def stream_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
@@ -602,68 +609,18 @@ def stream_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
                          n_logical: Optional[int] = None, mesh=None,
                          use_kernel: Optional[bool] = None):
     """One bucket's STREAMED aggregation (docs/architecture.md §13):
-    the :func:`fused_bucket_update` dispatch contract (plain call /
-    shard_map kernel / pjit oracle), returning ONLY the new server vector.
-    The caller applies the selected-client reset as a churn-bounded scatter
-    of this row into the donated client/init buffers — unselected rows are
-    never rewritten, so per-bucket round traffic drops from ~2R+2W to
-    1R (+ O(s * Dp) scatter writes) per resident byte. Bit-identical
-    server to ``fused_bucket_update`` per dispatch path."""
-    if progress_b is not None and progress_codes_b is not None:
-        raise ValueError("progress_b and progress_codes_b are mutually "
-                         "exclusive")
-    if mesh is None or spec.shards(b) <= 1:
-        return favas_stream_flat(server_b, trained_b, inits_b, alpha_p,
-                                 mask_p, float(s), progress=progress_b,
-                                 progress_codes=progress_codes_b,
-                                 progress_bits=progress_bits,
-                                 progress_shards=max(1, spec.shards(b)),
-                                 client_tile=spec.client_tile,
-                                 n_logical=n_logical, use_kernel=use_kernel)
-    kernel_active = (use_kernel if use_kernel is not None
-                     else jax.default_backend() == "tpu")
-    from jax.sharding import PartitionSpec as P
-    lane, row, vec = P(spec.mesh_axis), P(None, spec.mesh_axis), P(None)
-    if kernel_active:
-        from jax.experimental.shard_map import shard_map
-
-        def body(*ops):
-            pr = pc = None
-            if progress_b is not None:
-                srv, cli, ini, pr, al, mk = ops
-            elif progress_codes_b is not None:
-                srv, cli, ini, cd, sc, al, mk = ops
-                pc = {"codes": cd, "scale": sc}
-            else:
-                srv, cli, ini, al, mk = ops
-            return favas_stream_flat(srv, cli, ini, al, mk, float(s),
-                                     progress=pr, progress_codes=pc,
-                                     progress_bits=progress_bits,
-                                     progress_shards=1,
-                                     client_tile=spec.client_tile,
-                                     n_logical=n_logical, use_kernel=True)
-
-        operands = [server_b, trained_b, inits_b]
-        in_specs = [lane, row, row]
-        if progress_b is not None:
-            operands.append(progress_b)
-            in_specs.append(row)
-        elif progress_codes_b is not None:
-            operands += [progress_codes_b["codes"], progress_codes_b["scale"]]
-            in_specs += [row, row]
-        operands += [alpha_p, mask_p]
-        in_specs += [vec, vec]
-        return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                         out_specs=lane, check_rep=False)(*operands)
-    from jax.sharding import NamedSharding
-    out = favas_stream_flat(server_b, trained_b, inits_b, alpha_p, mask_p,
-                            float(s), progress=progress_b,
-                            progress_codes=progress_codes_b,
-                            progress_bits=progress_bits,
-                            progress_shards=spec.shards(b),
-                            client_tile=spec.client_tile,
-                            n_logical=n_logical, use_kernel=False)
-    return jax.lax.with_sharding_constraint(out, NamedSharding(mesh, lane))
+    the :func:`fused_bucket_update` dispatch contract, returning ONLY the
+    new server vector. The caller applies the selected-client reset as a
+    churn-bounded scatter of this row into the donated client/init
+    buffers — unselected rows are never rewritten, so per-bucket round
+    traffic drops from ~2R+2W to 1R (+ O(s * Dp) scatter writes) per
+    resident byte. Bit-identical server to ``fused_bucket_update`` per
+    dispatch path."""
+    return _bucket_dispatch(
+        favas_stream_flat, spec, b, server_b, trained_b, inits_b, alpha_p,
+        mask_p, s, progress_b=progress_b, progress_codes_b=progress_codes_b,
+        progress_bits=progress_bits, n_logical=n_logical, mesh=mesh,
+        use_kernel=use_kernel, stacked_outputs=False)
 
 
 def _streamed_reset(spec: FlatSpec, mesh, bufs, sel_idx, rows):

@@ -70,7 +70,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.luq import dequant_block
+from repro.kernels.luq import LANES, dequant_block, lane_scales
 
 TILE = 2048        # lane-dim tile; multiple of 128
 CLIENT_TILE = 32   # sublane-dim tile over client rows; multiple of 8
@@ -88,6 +88,17 @@ def _pad_clients(n: int, client_tile: int, arrays, alpha, mask):
     return n + rpad, arrays, alpha, mask
 
 
+def _pad_codes(codes, bits: int, pad: int):
+    """Lane-pad packed progress codes by ``pad`` zero codes (zero codes
+    decode to exact zeros, matching the zero-padded dense operands). The
+    padded row is re-packed through the storage layout: an unaligned row
+    is one packing group (``kernels.luq.pack_group``), the padded row is
+    not. The engine's buffers are tile-aligned and never take this path."""
+    from repro.core.paging import pack_codes, unpack_codes  # lazy: no cycle
+    return pack_codes(jnp.pad(unpack_codes(codes, bits), ((0, 0), (0, pad))),
+                      bits)
+
+
 def fused_block_vmem_bytes(n: int, dtype, *, progress: bool = False,
                            codec_bits: int = 0, tile: int = TILE,
                            client_tile: int = CLIENT_TILE,
@@ -100,7 +111,7 @@ def fused_block_vmem_bytes(n: int, dtype, *, progress: bool = False,
 
     ``codec_bits`` > 0 accounts the CODES-IN progress operand instead of a
     dense row block: a bit-packed (rows, tile*bits/8) uint8 codes block
-    plus a (rows, 1) f32 scale block — the codec term of docs/
+    plus a (rows, 128) f32 lane-broadcast scale block — the codec term of docs/
     architecture.md §10. At n=1024/fp32/bits=8 the total stays ~1.1 MiB
     (vs 1.29 MiB for the dense-progress operand), pinned < 2 MiB by
     tests/test_quant_fused.py.
@@ -128,7 +139,7 @@ def fused_block_vmem_bytes(n: int, dtype, *, progress: bool = False,
     inputs = srv_block + n_row_in * row_block + 2 * scalar_block
     if codec_bits:
         inputs += rows * tile * codec_bits // 8  # packed progress codes
-        inputs += rows * 4                       # (rows, 1) f32 scale block
+        inputs += rows * LANES * 4               # (rows, 128) f32 scale block
     if schedule == "streamed":
         outputs = srv_block                      # server row only
         scratch = tile * 4 if n > client_tile else 0      # f32 acc
@@ -186,7 +197,7 @@ def _agg_kernel_tiled(server_ref, clients_ref, inits_ref, coef_ref, mask_ref,
 
 def favas_agg_pallas(server, clients, inits, alpha, mask, s: float,
                      *, client_tile: int | None = None,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """Single-output FAVAS aggregation kernel (Algorithm 1 line 10 + eq. 3).
 
     Args:
@@ -199,7 +210,7 @@ def favas_agg_pallas(server, clients, inits, alpha, mask, s: float,
         ``n <= client_tile`` keeps the whole client axis resident in one
         block, larger n streams blocks through the VMEM accumulator.
       interpret: run the kernel in Pallas interpret mode (CPU validation);
-        pass False on TPU for the compiled kernel.
+        the default compiles it for the TPU.
 
     Returns the (D,) new server vector in the server's dtype. Lane padding
     to ``TILE`` happens here if D is unaligned (the flat-buffer engine
@@ -314,8 +325,7 @@ def _fused_kernel_codes(server_ref, clients_ref, inits_ref, codes_ref,
     i = inits_ref[...].astype(jnp.float32)            # (n, T)
     a = alpha_ref[...].astype(jnp.float32)            # (n, 1)
     m = mask_ref[...].astype(jnp.float32)             # (n, 1)
-    p = dequant_block(codes_ref[...],
-                      pscale_ref[...].astype(jnp.float32), bits)
+    p = dequant_block(codes_ref[...], pscale_ref[:, :1], bits)
     msg = i + p / a
     total = jnp.sum(m * msg, axis=0, keepdims=True)   # (1, T)
     s_new = (server_ref[...].astype(jnp.float32) + total) / s1
@@ -345,8 +355,7 @@ def _fused_kernel_tiled(server_ref, clients_ref, inits_ref, alpha_ref,
         if has_progress:
             p = prog_ref[...].astype(jnp.float32)
         elif codes_ref is not None:
-            p = dequant_block(codes_ref[...],
-                              pscale_ref[...].astype(jnp.float32), bits)
+            p = dequant_block(codes_ref[...], pscale_ref[:, :1], bits)
         else:
             p = c - i
         msg = i + p / a
@@ -382,7 +391,7 @@ def favas_fused_pallas(server, clients, inits, alpha, mask, s: float,
                        *, progress=None, progress_codes=None,
                        progress_bits: int = 0, progress_shards: int = 1,
                        client_tile: int | None = None,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """Fused aggregation + selected-client reset over flat buffers.
 
     server: (D,) f32/bf16; clients/inits: (n, D); alpha/mask: (n,).
@@ -425,13 +434,13 @@ def favas_fused_pallas(server, clients, inits, alpha, mask, s: float,
         if progress is not None:
             progress = jnp.pad(progress, ((0, 0), (0, pad)))
         if codes is not None:
-            # zero codes decode to exact zeros — the padded lanes transmit
-            # nothing, matching the zero-padded dense operands
-            codes = jnp.pad(codes, ((0, 0), (0, pad * bits // 8)))
+            codes = _pad_codes(codes, bits, pad)
     Dp = D + pad
-    # lane tiles per shard segment: the (rows, 1) scale block for lane tile
-    # i sits at column i // seg_tiles (shards == 1 makes this column 0)
+    # lane tiles per shard segment: the (rows, 128) scale block for lane
+    # tile i sits at column block i // seg_tiles (0 when shards == 1)
     seg_tiles = (Dp // progress_shards) // TILE if codes is not None else 1
+    if codes is not None:
+        pscale = lane_scales(pscale)
 
     if n <= ct:                                   # whole client axis resident
         alphac = jnp.maximum(alpha.astype(jnp.float32), 1e-9).reshape(n, 1)
@@ -445,7 +454,7 @@ def favas_fused_pallas(server, clients, inits, alpha, mask, s: float,
             in_specs = [srv_spec, row_spec, row_spec,
                         pl.BlockSpec((n, TILE * bits // 8),
                                      lambda i: (0, i)),
-                        pl.BlockSpec((n, 1),
+                        pl.BlockSpec((n, LANES),
                                      lambda i: (0, i // seg_tiles)),
                         scalar_spec, scalar_spec]
             operands = (server.reshape(1, Dp), clients, inits, codes,
@@ -501,7 +510,8 @@ def favas_fused_pallas(server, clients, inits, alpha, mask, s: float,
             (ct, TILE * bits // 8),
             lambda i, j: (jnp.minimum(j, nb - 1), i))
         pscale_spec = pl.BlockSpec(
-            (ct, 1), lambda i, j: (jnp.minimum(j, nb - 1), i // seg_tiles))
+            (ct, LANES),
+            lambda i, j: (jnp.minimum(j, nb - 1), i // seg_tiles))
         in_specs = [srv_spec, row_spec, row_spec, codes_spec, pscale_spec,
                     scalar_spec, scalar_spec]
         operands = (server.reshape(1, Dp), clients, inits, codes, pscale,
@@ -588,8 +598,7 @@ def _stream_kernel(server_ref, clients_ref, inits_ref, alpha_ref, mask_ref,
     if prog_ref is not None:
         p = prog_ref[...].astype(jnp.float32)
     elif codes_ref is not None:
-        p = dequant_block(codes_ref[...],
-                          pscale_ref[...].astype(jnp.float32), bits)
+        p = dequant_block(codes_ref[...], pscale_ref[:, :1], bits)
     else:
         p = c - i
     msg = i + p / a
@@ -615,8 +624,7 @@ def _stream_kernel_tiled(server_ref, clients_ref, inits_ref, alpha_ref,
     if prog_ref is not None:
         p = prog_ref[...].astype(jnp.float32)
     elif codes_ref is not None:
-        p = dequant_block(codes_ref[...],
-                          pscale_ref[...].astype(jnp.float32), bits)
+        p = dequant_block(codes_ref[...], pscale_ref[:, :1], bits)
     else:
         p = c - i
     msg = i + p / a
@@ -640,7 +648,7 @@ def favas_stream_pallas(server, clients, inits, alpha, mask, s: float,
                         *, progress=None, progress_codes=None,
                         progress_bits: int = 0, progress_shards: int = 1,
                         client_tile: int | None = None,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """Aggregation-only half of the STREAMED round schedule.
 
     Same operand contract as ``favas_fused_pallas`` (server (D,), clients/
@@ -676,9 +684,11 @@ def favas_stream_pallas(server, clients, inits, alpha, mask, s: float,
         if progress is not None:
             progress = jnp.pad(progress, ((0, 0), (0, pad)))
         if codes is not None:
-            codes = jnp.pad(codes, ((0, 0), (0, pad * bits // 8)))
+            codes = _pad_codes(codes, bits, pad)
     Dp = D + pad
     seg_tiles = (Dp // progress_shards) // TILE if codes is not None else 1
+    if codes is not None:
+        pscale = lane_scales(pscale)
 
     if n <= ct:                                   # whole client axis resident
         alphac = jnp.maximum(alpha.astype(jnp.float32), 1e-9).reshape(n, 1)
@@ -696,7 +706,7 @@ def favas_stream_pallas(server, clients, inits, alpha, mask, s: float,
             in_specs = [srv_spec, row_spec, row_spec,
                         pl.BlockSpec((n, TILE * bits // 8),
                                      lambda i: (0, i)),
-                        pl.BlockSpec((n, 1),
+                        pl.BlockSpec((n, LANES),
                                      lambda i: (0, i // seg_tiles)),
                         scalar_spec, scalar_spec]
             operands = (server.reshape(1, Dp), clients, inits, codes,
@@ -747,7 +757,8 @@ def favas_stream_pallas(server, clients, inits, alpha, mask, s: float,
         in_specs = [srv_spec, row_spec, row_spec,
                     pl.BlockSpec((ct, TILE * bits // 8),
                                  lambda i, j: (j, i)),
-                    pl.BlockSpec((ct, 1), lambda i, j: (j, i // seg_tiles)),
+                    pl.BlockSpec((ct, LANES),
+                                 lambda i, j: (j, i // seg_tiles)),
                     scalar_spec, scalar_spec]
         operands = (server.reshape(1, Dp), clients, inits, codes, pscale,
                     alphac, maskc)

@@ -9,8 +9,9 @@ stochastic exponent rounding) in one VMEM pass over (8, 128)-aligned tiles:
 * ``luq_encode_pallas`` — code-EMITTING variant: x + uniforms -> bit-packed
   uint8 codes + per-(row, shard) f32 scales, bit-identical to
   ``core.paging.luq_encode_rows`` under the same uniforms. The pack runs
-  in-kernel (strided lane slices + shifts) so the stored representation
-  never leaves VMEM wider than ``bits/8`` bytes per element.
+  in-kernel (contiguous 128-lane slices + shifts, see :func:`pack_group`)
+  so the stored representation never leaves VMEM wider than ``bits/8``
+  bytes per element.
 * ``luq_decode_pallas`` — code-CONSUMING inverse, bit-identical to
   ``core.paging.luq_decode_rows``.
 
@@ -33,6 +34,7 @@ from jax.experimental import pallas as pl
 ROWS, COLS = 256, 1024  # (sublane, lane) tile — multiples of (8, 128)
 ENC_ROWS = 8            # codec kernels: sublane rows per block
 ENC_TILE = 512          # codec kernels: lane tile; 512*bits/8 >= 128 packed
+LANES = 128             # vreg lane width: one packed plane
 
 
 def guard_scale(scale):
@@ -43,29 +45,56 @@ def guard_scale(scale):
                      jnp.where(scale > 0, scale, 1.0))
 
 
+def pack_group(width: int, bits: int) -> int:
+    """Codes per packing group of a ``width``-code row at ``bits``.
+
+    The packed layout is group-planar: a group of ``G = 128 * (8 // bits)``
+    codes packs into 128 bytes, and byte j of the group holds codes
+    ``j, j + 128, ..., j + 128 * (k - 1)`` (k = 8 // bits), plane i in bits
+    ``[i*bits, (i+1)*bits)``. Packing and unpacking are then shifts of
+    contiguous 128-lane slices — the only lane access the chip's compiler
+    accepts (strided lane slices are refused). A row whose width is not a
+    multiple of G is one group of ``width // k`` bytes per plane; that
+    only happens at small validation widths, never on the engine's
+    lane-tile-padded buffers."""
+    g = LANES * (8 // bits)
+    return g if width % g == 0 else width
+
+
 def pack_block(codes, bits: int):
     """In-kernel bit pack: (R, C) int32 codes < 2**bits -> (R, C*bits/8)
-    uint8, LSB-first — the layout of ``core.paging.pack_codes``. Strided
-    lane slices + shifts only; C must divide by 8//bits."""
+    uint8 in the group-planar layout of :func:`pack_group` — the layout of
+    ``core.paging.pack_codes``. C must divide by 8//bits."""
     k = 8 // bits
     if k == 1:
         return codes.astype(jnp.uint8)
-    packed = codes[:, 0::k]
-    for i in range(1, k):
-        packed = packed | (codes[:, i::k] << (i * bits))
-    return packed.astype(jnp.uint8)
+    width = codes.shape[-1]
+    g = pack_group(width, bits)
+    p = g // k
+    groups = []
+    for s in range(0, width, g):
+        packed = codes[:, s:s + p]
+        for i in range(1, k):
+            packed = packed | (codes[:, s + i * p:s + (i + 1) * p]
+                               << (i * bits))
+        groups.append(packed)
+    out = groups[0] if len(groups) == 1 else jnp.concatenate(groups, axis=1)
+    return out.astype(jnp.uint8)
 
 
 def unpack_block(packed, bits: int):
     """In-kernel inverse of :func:`pack_block`: (R, P) uint8 -> (R, P*8/
-    bits) int32 codes, via a k-fold lane repeat + per-lane shift (iota)."""
+    bits) int32 codes — k shifted copies of each contiguous packed group,
+    laid side by side."""
     k = 8 // bits
     c = packed.astype(jnp.int32)
     if k == 1:
         return c
-    rep = jnp.repeat(c, k, axis=1)
-    sub = jax.lax.broadcasted_iota(jnp.int32, rep.shape, 1) % k
-    return (rep >> (sub * bits)) & ((1 << bits) - 1)
+    p = pack_group(c.shape[-1] * k, bits) // k
+    mask = (1 << bits) - 1
+    planes = [(c[:, s:s + p] >> (i * bits)) & mask
+              for s in range(0, c.shape[-1], p) for i in range(k)]
+    return planes[0] if len(planes) == 1 else jnp.concatenate(planes, axis=1)
 
 
 def dequant_block(packed, scale, bits: int):
@@ -101,8 +130,9 @@ def _luq_kernel(x_ref, up_ref, ur_ref, scale_ref, out_ref, *, levels: int):
     out_ref[...] = (sign * scale * q).astype(out_ref.dtype)
 
 
-def luq_pallas(x, u_prune, u_round, bits: int, *, interpret: bool = True):
-    """Elementwise over any shape; flattened to (R, COLS) tiles."""
+def luq_pallas(x, u_prune, u_round, bits: int, *, interpret: bool = False):
+    """Elementwise over any shape; flattened to (R, COLS) tiles.
+    ``interpret`` runs the Pallas interpreter (CPU validation)."""
     # lazy: core.__init__ transitively imports this module, so a top-level
     # import of core.quant would be circular from some entry points
     from repro.core.quant import luq_scale
@@ -149,15 +179,31 @@ def luq_pallas(x, u_prune, u_round, bits: int, *, interpret: bool = True):
 # tests/test_quant_codec.py / tests/test_quant_fused.py).
 # ---------------------------------------------------------------------------
 
-def _codec_tile(seg: int, k: int):
+def lane_scales(scale):
+    """(rows, S) per-(row, shard) scales -> (rows, S * 128) f32, each scale
+    repeated across one 128-lane column block. A ``(rows, 128)`` block of
+    this array satisfies the chip's (8, 128) block rule for any shard
+    count S, where a ``(rows, 1)`` block of the (rows, S) array does not;
+    kernels read lane 0 of their block."""
+    return jnp.repeat(scale.astype(jnp.float32), LANES, axis=1)
+
+
+def _codec_tile(D: int, shards: int, bits: int):
     """Lane tile for the codec grid: ``ENC_TILE`` when the per-shard
     segment is tile-aligned (always true on the engine path, where shard
     segments are multiples of the 2048-lane kernel tile), else the whole
     segment — an interpret-mode validation shape, not a TPU layout."""
+    k = 8 // bits
+    seg = D // shards
     if seg % k:
         raise ValueError(f"segment width {seg} does not divide into "
-                         f"{8 // k}-bit groups of {k}")
-    return ENC_TILE if seg % ENC_TILE == 0 else seg
+                         f"{bits}-bit groups of {k}")
+    if seg % ENC_TILE == 0:
+        return ENC_TILE
+    if shards > 1 and k > 1 and seg % pack_group(D, bits):
+        raise ValueError(f"shard segment {seg} splits a {bits}-bit packing "
+                         f"group of the {D}-wide row")
+    return seg
 
 
 def _luq_encode_kernel(x_ref, up_ref, ur_ref, scale_ref, out_ref,
@@ -165,7 +211,7 @@ def _luq_encode_kernel(x_ref, up_ref, ur_ref, scale_ref, out_ref,
     x = x_ref[...].astype(jnp.float32)
     up = up_ref[...].astype(jnp.float32)
     ur = ur_ref[...].astype(jnp.float32)
-    scale = scale_ref[...].astype(jnp.float32)        # (R, 1), pre-guarded
+    scale = scale_ref[:, :1]                          # (R, 1), pre-guarded
     m = jnp.abs(x) / scale
     min_level = 2.0 ** (-(levels - 1))
     below = m < min_level
@@ -181,27 +227,27 @@ def _luq_encode_kernel(x_ref, up_ref, ur_ref, scale_ref, out_ref,
 
 
 def _luq_decode_kernel(codes_ref, scale_ref, out_ref, *, bits: int):
-    scale = scale_ref[...].astype(jnp.float32)        # (R, 1)
-    v = dequant_block(codes_ref[...], scale, bits)
+    v = dequant_block(codes_ref[...], scale_ref[:, :1], bits)
     out_ref[...] = v.astype(out_ref.dtype)
 
 
 def luq_encode_pallas(x, u_prune, u_round, bits: int, *, shards: int = 1,
-                      interpret: bool = True):
+                      interpret: bool = False):
     """LUQ-encode (rows, D) to bit-packed codes + per-(row, shard) scales.
 
     The kernel-path twin of ``core.paging.luq_encode_rows``: given the SAME
     (rows, D) uniform fields it emits bit-identical packed codes and
     scales. The per-(row, shard) max-|x| scale is a cheap jnp reduction
     (identical to the oracle's); all elementwise math and the bit pack run
-    in one VMEM pass per (8, tile) block, with the scale riding a (8, 1)
-    block indexed by ``lane_tile // tiles_per_shard``."""
+    in one VMEM pass per (8, tile) block, with the scale riding an (8, 128)
+    block of :func:`lane_scales` indexed by ``lane_tile // tiles_per_shard``.
+    ``interpret`` runs the Pallas interpreter (CPU validation)."""
     levels = 2 ** (bits - 1) - 1
     rows, D = x.shape
     if D % shards:
         raise ValueError(f"D={D} does not divide into {shards} shards")
     seg = D // shards
-    tile = _codec_tile(seg, 8 // bits)
+    tile = _codec_tile(D, shards, bits)
     seg_tiles = seg // tile
     xf = x.astype(jnp.float32)
     scale = guard_scale(jnp.max(jnp.abs(xf.reshape(rows, shards, seg)),
@@ -223,18 +269,19 @@ def luq_encode_pallas(x, u_prune, u_round, bits: int, *, shards: int = 1,
             pl.BlockSpec((ENC_ROWS, tile), lambda i, c: (i, c)),
             pl.BlockSpec((ENC_ROWS, tile), lambda i, c: (i, c)),
             pl.BlockSpec((ENC_ROWS, tile), lambda i, c: (i, c)),
-            pl.BlockSpec((ENC_ROWS, 1), lambda i, c: (i, c // seg_tiles)),
+            pl.BlockSpec((ENC_ROWS, LANES),
+                         lambda i, c: (i, c // seg_tiles)),
         ],
         out_specs=pl.BlockSpec((ENC_ROWS, tile * bits // 8),
                                lambda i, c: (i, c)),
         out_shape=jax.ShapeDtypeStruct((rp, D * bits // 8), jnp.uint8),
         interpret=interpret,
-    )(xf, up, ur, scale_p)
+    )(xf, up, ur, lane_scales(scale_p))
     return {"codes": packed[:rows], "scale": scale}
 
 
 def luq_decode_pallas(enc, bits: int, dtype, *, shards: int = 1,
-                      interpret: bool = True):
+                      interpret: bool = False):
     """Inverse of :func:`luq_encode_pallas` -> (rows, D) in ``dtype``;
     bit-identical to ``core.paging.luq_decode_rows`` on the same encoding.
     The unpack + dequant run in one VMEM pass per packed block."""
@@ -245,7 +292,7 @@ def luq_decode_pallas(enc, bits: int, dtype, *, shards: int = 1,
     if D % shards:
         raise ValueError(f"D={D} does not divide into {shards} shards")
     seg = D // shards
-    tile = _codec_tile(seg, k)
+    tile = _codec_tile(D, shards, bits)
     seg_tiles = seg // tile
     rpad = (-rows) % ENC_ROWS
     scale_p = scale
@@ -258,10 +305,11 @@ def luq_decode_pallas(enc, bits: int, dtype, *, shards: int = 1,
         grid=(rp // ENC_ROWS, D // tile),
         in_specs=[
             pl.BlockSpec((ENC_ROWS, tile * bits // 8), lambda i, c: (i, c)),
-            pl.BlockSpec((ENC_ROWS, 1), lambda i, c: (i, c // seg_tiles)),
+            pl.BlockSpec((ENC_ROWS, LANES),
+                         lambda i, c: (i, c // seg_tiles)),
         ],
         out_specs=pl.BlockSpec((ENC_ROWS, tile), lambda i, c: (i, c)),
         out_shape=jax.ShapeDtypeStruct((rp, D), jnp.dtype(dtype)),
         interpret=interpret,
-    )(codes, scale_p)
+    )(codes, lane_scales(scale_p))
     return out[:rows]

@@ -25,6 +25,7 @@ exits non-zero unless every round completed and every child exited 0.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import multiprocessing as mp
 import os
@@ -42,6 +43,7 @@ from repro.launch.client import LocalSGDClient
 from repro.launch.server import (SERVER_ID, AsyncConfig, FavasAsyncServer,
                                  recover_server)
 from repro.models.classifier import accuracy, mlp_apply, mlp_init
+from repro.utils.compile_cache import setup_compile_cache
 
 
 def default_backoff(cfg: AsyncConfig) -> BackoffPolicy:
@@ -194,6 +196,22 @@ def run_inproc_chaos(cfg: AsyncConfig, data, *, d_hidden: int = 32,
 # real multi-process runner
 # ---------------------------------------------------------------------------
 
+@contextlib.contextmanager
+def _clients_on_cpu():
+    """Processes spawned inside the block start with ``JAX_PLATFORMS=cpu``.
+    Clients model edge devices and run on the host CPU: an accelerator
+    belongs to one process at a time, and that process is the server."""
+    old = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if old is None:
+            os.environ.pop("JAX_PLATFORMS", None)
+        else:
+            os.environ["JAX_PLATFORMS"] = old
+
+
 def _client_main(conn, payload, plan, seed, until):
     """Spawned-child entry: rebuild the worker from the picklable payload
     (the model init is re-derived from the seed, not shipped) and pump its
@@ -251,7 +269,8 @@ def run_proc(cfg: AsyncConfig, data, *, d_hidden: int = 32,
         p = ctx.Process(target=_client_main,
                         args=(child_c, payload, plan, seed, timeout + 30.0),
                         daemon=True)
-        p.start()
+        with _clients_on_cpu():
+            p.start()
         child_c.close()
         conns[cid], procs[cid] = parent_c, p
 
@@ -325,7 +344,11 @@ def run_proc_supervised(cfg: AsyncConfig, data, *, d_hidden: int = 32,
     re-wires the server-side pipes; client pushes that died with the old
     process are simply retried into the new one, where the exactly-once
     ledger sorts them out. Returns the final incarnation's result plus
-    ``crashes`` — CI gates on it being ``len(kill_at)``."""
+    ``crashes`` — CI gates on it being ``len(kill_at)``.
+
+    The supervisor itself never initializes a JAX backend (it only moves
+    pickled envelopes), so the server child is the one process that opens
+    an accelerator; clients run on the host CPU."""
     from multiprocessing import connection as mpc
     xtr, ytr, _, _, parts = data
     n_classes = int(ytr.max()) + 1
@@ -352,7 +375,8 @@ def run_proc_supervised(cfg: AsyncConfig, data, *, d_hidden: int = 32,
         p = ctx.Process(target=_client_main,
                         args=(child_c, payload, plan, seed, timeout + 30.0),
                         daemon=True)
-        p.start()
+        with _clients_on_cpu():
+            p.start()
         child_c.close()
         proxy_a[cid], client_procs[cid] = parent_c, p
 
@@ -495,6 +519,7 @@ def main(argv=None) -> int:
                          "child (proc transport only; requires --wal-dir)")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
+    setup_compile_cache()
     kill_at = tuple(float(x) for x in args.chaos.split(",") if x.strip())
     if kill_at and (args.transport != "proc" or not args.wal_dir):
         ap.error("--chaos needs --transport proc and --wal-dir")

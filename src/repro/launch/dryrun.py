@@ -26,25 +26,16 @@ from repro.launch import steps as STEPS
 from repro.launch.roofline import parse_hlo_collectives, build_report
 
 SHAPES = list(STEPS.INPUT_SHAPES)
+# the chip the production meshes stand for: the forced host devices compile
+# the program, the roofline terms use this kind's published peaks
+TARGET_KIND = "TPU v5 lite"
 
 
 def normalize_cost_analysis(cost) -> dict:
-    """``compiled.cost_analysis()`` returns a dict on older JAX, a LIST of
-    per-computation dicts on newer JAX (one per executable computation), or
-    None. Normalize to one flat dict, summing numeric keys across
-    computations, so ``cost.get("flops")`` works everywhere."""
-    if cost is None:
-        return {}
-    if isinstance(cost, dict):
-        return dict(cost)
-    merged = {}
-    for entry in cost:
-        for k, v in (entry or {}).items():
-            if isinstance(v, (int, float)):
-                merged[k] = merged.get(k, 0) + v
-            else:
-                merged.setdefault(k, v)
-    return merged
+    """``compiled.cost_analysis()`` as a plain dict — the installed JAX
+    returns one dict per executable, or None when the backend reports no
+    cost — so ``cost.get("flops")`` always works."""
+    return dict(cost or {})
 
 
 def run_one(arch: str, shape_name: str, mesh_name: str, *, out_dir=None,
@@ -104,7 +95,7 @@ def run_one(arch: str, shape_name: str, mesh_name: str, *, out_dir=None,
             arch, shape_name, mesh_name, cfg, STEPS.INPUT_SHAPES[shape_name],
             n_chips, model_shards, cost, coll,
             local_steps=fcfg.R if kind == "train" else 0,
-            param_bytes=4 if kind == "train" else 2)
+            param_bytes=4 if kind == "train" else 2, device_kind=TARGET_KIND)
         rec["roofline"] = {
             "compute_s": report.compute_s, "memory_s": report.memory_s,
             "collective_s": report.collective_s, "dominant": report.dominant,

@@ -6,16 +6,27 @@ multi pod  : (2, 16, 16)   axes ("pod", "data", "model")   = 512 chips
 FAVAS clients live on the ("pod", "data") product axis — one resident client
 per data-parallel coordinate; "model" is tensor parallelism. Defined as a
 FUNCTION so importing this module never touches jax device state.
+
+Every mesh is built with ``AxisType.Auto`` axes: the engine places its
+flat buffers with ``NamedSharding`` + ``with_sharding_constraint`` and
+lets GSPMD propagate through the flatten/unflatten reshapes. JAX 0.9's
+``jax.make_mesh`` defaults to ``Explicit`` axes, under which those
+reshapes raise ``ShardingTypeError``.
 """
 from __future__ import annotations
 
 import jax
 
 
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_model_mesh(n_model: int | None = None):
@@ -23,7 +34,7 @@ def make_model_mesh(n_model: int | None = None):
     meshes, and what the forced-8-CPU-device sharded tests / benchmarks run
     on. ``n_model=None`` uses every visible device."""
     n = n_model or len(jax.devices())
-    return jax.make_mesh((n,), ("model",))
+    return _auto_mesh((n,), ("model",))
 
 
 def mesh_from_arg(arg: str | None):

@@ -1,10 +1,11 @@
-"""Roofline analysis from compiled dry-run artifacts (CPU container — terms
-are *derived*, not timed; TPU v5e is the target).
+"""Roofline analysis from compiled dry-run artifacts (terms are *derived*
+from shapes and compiled HLO, not timed).
 
-Terms per (arch, shape, mesh), all in seconds:
-  compute    = FLOPs_per_chip / 197e12          (bf16 peak)
-  memory     = HBM_bytes_per_chip / 819e9
-  collective = collective_bytes_per_chip / 50e9 (per-link ICI)
+Terms per (arch, shape, mesh), all in seconds, against the target chip's
+published peaks (:data:`PEAKS`, keyed by ``device_kind``):
+  compute    = FLOPs_per_chip / flops          (bf16 peak)
+  memory     = HBM_bytes_per_chip / hbm_bw
+  collective = collective_bytes_per_chip / ici_bw (per-link ICI)
 
 Sources:
 * collective bytes — parsed from ``compiled.as_text()``; XLA:CPU while loops
@@ -24,9 +25,21 @@ import json
 import re
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12          # bf16 / chip, TPU v5e
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. TPU v5e:
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s ICI per chip over 4 links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def chip_peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of ``device_kind``; a kind without published peaks in
+    :data:`PEAKS` is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r};"
+                       f" known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -145,8 +158,10 @@ def dense_materializations(hlo_text: str, *, rows: int, min_cols: int = 128,
 # entry-output defining opcodes that do NOT rewrite the full buffer: the
 # output either aliases a donated input directly or is produced by an
 # in-place churn-bounded update (scatter / dynamic-update-slice; XLA:CPU
-# expands a row scatter to a while loop whose result surfaces through
-# get-tuple-element). Everything else writes the whole buffer.
+# either expands a row scatter to a while loop whose result surfaces
+# through get-tuple-element, or wraps it in a loop fusion whose root is the
+# scatter — a fusion counts as the opcode of its computation's ROOT).
+# Everything else writes the whole buffer.
 _IN_PLACE_OPS = frozenset({
     "parameter", "get-tuple-element", "dynamic-update-slice", "scatter",
     "bitcast", "copy-start", "copy-done", "optimization-barrier", "tuple",
@@ -154,7 +169,8 @@ _IN_PLACE_OPS = frozenset({
 _OPCODE_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*"
     r"(?:\([^)]*\)|[\w\[\]{},]+)\s+([\w\-]+)\(")
-_ROOT_OPERAND_RE = re.compile(r"(\w+)\[([\d,]*)\][^\s]*\s+%?([\w.\-]+)")
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
+_FUSION_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
 
 
 def pass_through_copies(hlo_text: str, *, rows: int, min_cols: int = 128,
@@ -175,35 +191,56 @@ def pass_through_copies(hlo_text: str, *, rows: int, min_cols: int = 128,
     tests/test_streaming.py beside the ``dense_materializations`` gate
     this mirrors). ``rows`` is the client-stack row count (n padded, or
     s_max-stack rows for a paged round)."""
-    lines = hlo_text.splitlines()
-    opcodes: Dict[str, str] = {}
-    for ln in lines:
-        m = _OPCODE_RE.match(ln)
-        if m:
-            opcodes[m.group(1)] = m.group(2)
-    # the ENTRY computation's ROOT line carries the typed operand list
-    root = None
-    in_entry = False
-    for ln in lines:
+    comps: Dict[str, List[str]] = {}
+    entry = cur = None
+    for ln in hlo_text.splitlines():
         s = ln.strip()
-        if s.startswith("ENTRY"):
-            in_entry = True
-        elif in_entry and s.startswith("ROOT"):
-            root = s
-            break
-        elif in_entry and s == "}":
-            in_entry = False
-    if root is None:
+        if cur is None:
+            m = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(", s)
+            if m and s.endswith("{") and "->" in s:
+                cur = m.group(1)
+                comps[cur] = []
+                entry = cur if s.startswith("ENTRY") else entry
+        elif s == "}":
+            cur = None
+        else:
+            comps[cur].append(s)
+    if entry is None:
         return []
-    args = root.split("(", 2)[-1]
+
+    def root_opcode(comp):
+        m = next((_OPCODE_RE.match(s) for s in comps.get(comp, ())
+                  if s.startswith("ROOT")), None)
+        return m.group(2) if m else "?"
+
+    defs: Dict[str, Tuple[str, str, str, str]] = {}    # name -> (op, dtype,
+    for s in comps[entry]:                              #  dims, line)
+        m, d = _OPCODE_RE.match(s), _DEF_RE.match(s)
+        if m and d:
+            defs[m.group(1)] = (m.group(2), d.group(2), d.group(3), s)
+    root = next((s for s in comps[entry] if s.startswith("ROOT")), "")
+    m = _OPCODE_RE.match(root)
+    if m is None:
+        return []
+    # the entry outputs: the ROOT tuple's operands, or the ROOT itself.
+    # Operands are looked up by name: XLA prints them untyped
+    # (``tuple(%a, %b)``)
+    outputs = ([m.group(1)] if m.group(2) != "tuple"
+               else _OPERAND_NAME_RE.findall(root.split("(", 2)[-1]))
     out = []
-    for dtype, dims, name in _ROOT_OPERAND_RE.findall(args):
+    for name in outputs:
+        if name not in defs:
+            continue
+        op, dtype, dims, line = defs[name]
         if dtype not in dtypes or not dims.strip():
             continue
         d = tuple(int(x) for x in dims.split(","))
         if len(d) < 2 or d[0] != rows or max(d[1:]) < min_cols:
             continue
-        op = opcodes.get(name, "?")
+        if op == "fusion":
+            callee = _FUSION_CALLS_RE.search(line)
+            if callee and root_opcode(callee.group(1)) in _IN_PLACE_OPS:
+                continue
         if op not in _IN_PLACE_OPS:
             out.append((name, op, d))
     return out
@@ -458,16 +495,19 @@ class RooflineReport:
 
 def build_report(arch: str, shape_name: str, mesh_name: str, cfg, shape_info,
                  n_chips: int, model_shards: int, cost: dict, coll: dict,
-                 local_steps: int = 0, param_bytes: int = 4) -> RooflineReport:
+                 local_steps: int = 0, param_bytes: int = 4, *,
+                 device_kind: str) -> RooflineReport:
+    """Roofline terms on ``device_kind`` chips (see :func:`chip_peaks`)."""
+    peaks = chip_peaks(device_kind)
     fl = analytic_flops(cfg, shape_info, n_chips, local_steps)
     by = analytic_bytes(cfg, shape_info, n_chips, model_shards, local_steps,
                         param_bytes)
     # compute term: prefer the trip-adjusted per-device dot FLOPs parsed from
     # the compiled HLO (counts remat recompute!); analytic as floor/fallback.
     hlo_flops_chip = float(coll.get("dot_flops", 0.0) or 0.0)
-    compute_s = max(hlo_flops_chip, fl["per_chip"]) / PEAK_FLOPS
-    memory_s = by / HBM_BW
-    coll_s = coll["total_bytes"] / ICI_BW
+    compute_s = max(hlo_flops_chip, fl["per_chip"]) / peaks["flops"]
+    memory_s = by / peaks["hbm_bw"]
+    coll_s = coll["total_bytes"] / peaks["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dominant = max(terms, key=terms.get)
     raw_flops = float(cost.get("flops", 0.0) or 0.0)

@@ -42,6 +42,7 @@ from repro.data import make_lm_corpus
 from repro.data.pipeline import BatchPrefetcher, lm_round_batch, \
     lm_superstep_batch
 from repro.models.model import init_params, loss_fn
+from repro.utils.compile_cache import setup_compile_cache
 from repro.utils.metrics import MetricsLogger
 
 
@@ -131,8 +132,15 @@ def build_cli():
     return ap
 
 
-def run(args):
-    cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
+def run(args, cfg=None):
+    """Train per the parsed CLI ``args``. ``cfg`` overrides the model
+    configuration that ``--arch``/``--reduced`` would select (a caller that
+    cuts a published config to size hands it in here). Returns ``(state,
+    losses, engine)``: the final engine state, every round's loss, and the
+    ``RoundEngine`` that ran them."""
+    if cfg is None:
+        cfg = (get_reduced_config(args.arch) if args.reduced
+               else get_config(args.arch))
     fcfg = FavasConfig(n_clients=args.n_clients, s_selected=args.s,
                        local_steps=args.K, eta=args.eta,
                        reweight=args.reweight, quant_bits=args.quant_bits,
@@ -290,11 +298,12 @@ def run(args):
     flush()
     print(f"done: first-10 loss {np.mean(losses[:10]):.4f} -> "
           f"last-10 {np.mean(losses[-10:]):.4f}")
-    return state, losses
+    return state, losses, engine
 
 
 def main():
     args = build_cli().parse_args()
+    setup_compile_cache()
     run(args)
 
 

@@ -23,7 +23,8 @@ def test_favas_agg_kernel_matches_ref(n, D, dtype):
     alpha = jax.random.uniform(ks[3], (n,), minval=1.0, maxval=8.0)
     mask = (jax.random.uniform(ks[4], (n,)) > 0.5).astype(jnp.float32)
     s = float(mask.sum())
-    out_k = favas_agg_pallas(server, clients, inits, alpha, mask, s)
+    out_k = favas_agg_pallas(server, clients, inits, alpha, mask, s,
+                             interpret=True)
     out_r = ref.favas_agg_ref(server, clients, inits, alpha, mask, s)
     # kernel fuses (mask*init + coef*(client-init)) * 1/(s+1); the ref
     # divides — identical in f32, but the bf16 OUTPUT cast can differ by
@@ -42,7 +43,7 @@ def test_luq_kernel_matches_ref(shape, bits, dtype):
     x = jax.random.normal(key, shape, dtype)
     up = jax.random.uniform(jax.random.fold_in(key, 1), shape)
     ur = jax.random.uniform(jax.random.fold_in(key, 2), shape)
-    out_k = luq_pallas(x, up, ur, bits)
+    out_k = luq_pallas(x, up, ur, bits, interpret=True)
     scale = jnp.max(jnp.abs(x.astype(jnp.float32)))
     out_r = ref.luq_ref(x, up, ur, scale, bits)
     np.testing.assert_allclose(np.asarray(out_k, np.float32),

@@ -45,7 +45,8 @@ def test_act_seq_axis_numerically_identical():
     key = jax.random.PRNGKey(1)
     params = init_params(key, cfg)
     toks = jax.random.randint(key, (B, S), 0, cfg.vocab_size_raw)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     base, _ = forward(params, cfg, {"tokens": toks})
     with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
             else mesh:

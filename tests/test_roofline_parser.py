@@ -79,3 +79,12 @@ def test_analytic_flops_moe_uses_active_params():
     assert fm["params"]["total"] > 35e9
     assert fm["params"]["active"] < 9e9
     assert fd["model_flops"] > 0 and fm["model_flops"] > 0
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    import pytest
+    from repro.launch.roofline import chip_peaks
+    v5e = chip_peaks("TPU v5 lite")
+    assert v5e["flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("cpu")

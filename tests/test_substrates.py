@@ -113,7 +113,8 @@ def test_param_specs_smoke():
     from repro.configs import get_reduced_config
     from repro.models.model import init_params
     from repro.sharding.rules import param_specs
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     for arch in ["llama3-8b", "granite-moe-3b-a800m", "mamba2-1.3b",
                  "recurrentgemma-2b"]:
         cfg = get_reduced_config(arch)
@@ -123,6 +124,15 @@ def test_param_specs_smoke():
         specs = param_specs(params, mesh, cfg)
         assert len(jax.tree_util.tree_leaves(
             specs, is_leaf=lambda x: x is None or hasattr(x, "index"))) > 0
+
+
+def test_model_mesh_axes_are_auto():
+    """The engine's meshes use Auto axes: JAX 0.9's ``jax.make_mesh``
+    defaults to Explicit, under which the engine's flatten/unflatten
+    reshapes of sharded buckets raise ``ShardingTypeError``."""
+    from repro.launch.mesh import make_model_mesh
+    mesh = make_model_mesh(1)
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
 
 
 # ------------------------------ theory -------------------------------------
@@ -178,3 +188,38 @@ def test_metrics_logger_jsonl(tmp_path):
     lg.close()
     lines = [_json.loads(l) for l in open(p)]
     assert len(lines) == 5 and lines[-1]["loss"] == 6.0
+
+
+# ------------------------------ placement ----------------------------------
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` is left to JAX; without it the cache
+    goes to the fixed, git-ignored ``.jax_cache/`` of the checkout."""
+    from repro.utils.compile_cache import CACHE_DIR, setup_compile_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert CACHE_DIR == os.path.join(root, ".jax_cache")
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert setup_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert setup_compile_cache() == CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_cluster_clients_start_on_cpu(monkeypatch):
+    """Client processes start with ``JAX_PLATFORMS=cpu`` (they model edge
+    devices; the accelerator belongs to the server), and the parent's own
+    setting comes back afterwards."""
+    from repro.launch.cluster import _clients_on_cpu
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with _clients_on_cpu():
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert os.environ["JAX_PLATFORMS"] == "tpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with _clients_on_cpu():
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert "JAX_PLATFORMS" not in os.environ

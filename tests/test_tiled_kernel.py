@@ -94,7 +94,8 @@ def test_agg_tiled_matches_ref(n, D):
     accumulator + @pl.when epilogue)."""
     server, clients, inits, alpha, mask, s = _fused_inputs(n, D, jnp.float32,
                                                            seed=n)
-    out_k = favas_agg_pallas(server, clients, inits, alpha, mask, s)
+    out_k = favas_agg_pallas(server, clients, inits, alpha, mask, s,
+                             interpret=True)
     out_r = ref.favas_agg_ref(server, clients, inits, alpha, mask, s)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=1e-6, atol=1e-6)
