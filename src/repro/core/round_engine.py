@@ -45,6 +45,7 @@ from repro.core.paging import PassthroughCodec, make_codec
 from repro.core.quant import quantize_tree
 from repro.kernels.favas_agg import CLIENT_TILE, TILE
 from repro.kernels.ops import favas_fused_flat, favas_stream_flat
+from repro.utils.profiling import scoped
 from repro.utils.tree import tree_map
 
 
@@ -286,6 +287,7 @@ def flatten_tree(spec: FlatSpec, tree) -> tuple:
     return tuple(out)
 
 
+@scoped("favas_relayout")
 def flatten_stacked(spec: FlatSpec, tree) -> tuple:
     """Client-stacked pytree (leading axis n) -> tuple of (Np_b, Dp_b).
 
@@ -352,6 +354,7 @@ def unflatten_tree(spec: FlatSpec, bufs: Sequence):
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
 
 
+@scoped("favas_relayout")
 def unflatten_stacked(spec: FlatSpec, bufs: Sequence):
     """Tuple of (Np_b, Dp_b) buffers -> client-stacked pytree (padded client
     rows, if any, are dropped)."""
@@ -384,6 +387,7 @@ def unflatten_stacked(spec: FlatSpec, bufs: Sequence):
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
 
 
+@scoped("favas_aggregate")
 def pad_client_vec(spec: FlatSpec, v, fill: float = 0.0):
     """(n,) per-client vector -> (Np,) padded to the spec's stored rows.
     ``fill``: value for padded rows (0 for masks — padded rows are never
@@ -503,6 +507,7 @@ def _constrain_cold(spec: FlatSpec, mesh, cold) -> tuple:
     return tuple(out)
 
 
+@scoped("favas_aggregate")
 def _bucket_dispatch(flat_fn, spec: FlatSpec, b: int, server_b, trained_b,
                      inits_b, alpha_p, mask_p, s: float, *, progress_b,
                      progress_codes_b, progress_bits: int, n_logical, mesh,
@@ -623,6 +628,7 @@ def stream_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
         use_kernel=use_kernel, stacked_outputs=False)
 
 
+@scoped("favas_reset")
 def _streamed_reset(spec: FlatSpec, mesh, bufs, sel_idx, rows):
     """Churn-bounded selected-client reset: scatter each bucket's new server
     row into the ``sel_idx`` positions of the (donated) state buffers.
@@ -654,6 +660,7 @@ def slab_shardings(spec: FlatSpec, mesh):
         for b in range(spec.n_buckets))
 
 
+@scoped("favas_encode_progress")
 def _encode_progress(spec: FlatSpec, trained, inits, k_q, bits: int, *,
                      mesh=None, use_kernel: Optional[bool] = None) -> tuple:
     """Per-bucket LUQ encode of the transmitted progress (``quant_fused``
@@ -681,6 +688,19 @@ def _encode_progress(spec: FlatSpec, trained, inits, k_q, bits: int, *,
                          x, NamedSharding(mesh, lane)), pc)
                  for b, pc in enumerate(codes)]
     return tuple(codes)
+
+
+@scoped("favas_encode_progress")
+def _tree_progress(spec: FlatSpec, mesh, trained_tree, inits, k_q,
+                   bits: int) -> tuple:
+    """FAVAS[QNN] transmitted progress in tree space: per-leaf LUQ of
+    ``trained - inits`` (the seed's per-leaf scales and keys), as stacked
+    bucket buffers."""
+    inits_tree = unflatten_stacked(spec, inits)
+    prog = quantize_tree(tree_map(jnp.subtract, trained_tree, inits_tree),
+                         bits, k_q)
+    return _constrain_buckets(spec, mesh, flatten_stacked(spec, prog),
+                              stacked=True)
 
 
 # ---------------------------------------------------------------------------
@@ -799,6 +819,7 @@ def engine_init(spec: FlatSpec, params, cfg, key, *,
 # The round
 # ---------------------------------------------------------------------------
 
+@scoped("favas_local_training")
 def _local_training(loss_fn: Callable, cfg, clients_tree, counters,
                     new_counters, batch):
     """Masked R-step local SGD, vmapped over the client axis.
@@ -949,11 +970,8 @@ def engine_round(spec: FlatSpec, state: EngineState, batch=None, *, cfg,
         # implementation). Quantization is communication-only (Remark 1):
         # the fused pass aggregates Q(progress) but resets unselected
         # clients to their full-precision trained state.
-        inits_tree = unflatten_stacked(spec, state.inits)
-        prog = quantize_tree(tree_map(jnp.subtract, trained_tree, inits_tree),
-                             cfg.quant_bits, k_q)
-        progress = _constrain_buckets(spec, mesh, flatten_stacked(spec, prog),
-                                      stacked=True)
+        progress = _tree_progress(spec, mesh, trained_tree, state.inits, k_q,
+                                  cfg.quant_bits)
 
     # 4+5. aggregation + selected-client reset: one pass per bucket.
     # alpha/mask ride to the kernel padded alongside the buffers' client
@@ -1009,6 +1027,9 @@ def engine_round(spec: FlatSpec, state: EngineState, batch=None, *, cfg,
         "mean_steps": jnp.mean(new_counters.astype(jnp.float32)),
         "selected": jnp.sum(m),
         "stale_rounds": jnp.max(stale_new).astype(jnp.float32),
+        # useful over attempted: every client ran R forward/backward steps
+        "live_steps": total_live.astype(jnp.int32),
+        "computed_steps": jnp.int32(n * cfg.R),
     }
     return new_state, metrics
 
@@ -1105,56 +1126,58 @@ def _paged_round(spec: FlatSpec, state: EngineState, batch, *, cfg,
     # Unique sorted scatter ids + donation => in-place read-modify-write;
     # non-evicted clients' cold bytes are untouched. The encode key is
     # FOLDED off k_q (not split), leaving the dense key chain intact.
-    k_evict = jax.random.fold_in(k_q, 1)
-    evict_ids = old_ids[evict_pos]
-    # host tier: churn ids become slab rows; invalid slots hit the all-zero
-    # dummy row and write back its own gathered value (a no-op). The id
-    # spaces differ but the ENCODED BYTES are identical — the codec key
-    # chain never branches on placement.
-    evict_rows = plan["evict_slab"] if host_cold else evict_ids
-    pools = slab if host_cold else state.cold
-    cold = []
-    for b in range(spec.n_buckets):
-        enc = codec.encode_pair(
-            state.clients[b][evict_pos], state.inits[b][evict_pos],
-            jax.random.fold_in(k_evict, b), shards=spec.shards(b),
-            use_kernel=use_kernel)
+    with jax.named_scope("favas_evict"):
+        k_evict = jax.random.fold_in(k_q, 1)
+        evict_ids = old_ids[evict_pos]
+        # host tier: churn ids become slab rows; invalid slots hit the all-zero
+        # dummy row and write back its own gathered value (a no-op). The id
+        # spaces differ but the ENCODED BYTES are identical — the codec key
+        # chain never branches on placement.
+        evict_rows = plan["evict_slab"] if host_cold else evict_ids
+        pools = slab if host_cold else state.cold
+        cold = []
+        for b in range(spec.n_buckets):
+            enc = codec.encode_pair(
+                state.clients[b][evict_pos], state.inits[b][evict_pos],
+                jax.random.fold_in(k_evict, b), shards=spec.shards(b),
+                use_kernel=use_kernel)
 
-        def scatter(pool, e):
-            sel = evict_valid.reshape((-1,) + (1,) * (e.ndim - 1))
-            return pool.at[evict_rows].set(
-                jnp.where(sel, e.astype(pool.dtype), pool[evict_rows]))
+            def scatter(pool, e):
+                sel = evict_valid.reshape((-1,) + (1,) * (e.ndim - 1))
+                return pool.at[evict_rows].set(
+                    jnp.where(sel, e.astype(pool.dtype), pool[evict_rows]))
 
-        cold.append(jax.tree_util.tree_map(scatter, pools[b], enc))
-    cold = _constrain_cold(spec, mesh, cold)
+            cold.append(jax.tree_util.tree_map(scatter, pools[b], enc))
+        cold = _constrain_cold(spec, mesh, cold)
 
     # 4. promote: gather + dequant ONLY the rows entering the hot set. Rows
     # that never went cold keep their full-precision buffers — surviving
     # hot clients pay NO requant round-trip.
-    rpad = spec.stacked_rows - s_hot
-    promo_ids = members[promo_pos]
-    promo_rows = plan["promo_slab"] if host_cold else promo_ids
-    clients_hot, inits_hot = [], []
-    for b in range(spec.n_buckets):
-        dt = jnp.dtype(spec.bucket_dtypes[b])
-        enc_rows = jax.tree_util.tree_map(lambda p: p[promo_rows], cold[b])
-        dec_cli, dec_ini = codec.decode_pair(enc_rows, dt,
-                                             shards=spec.shards(b),
-                                             use_kernel=use_kernel)
-        base_cli = state.clients[b][pos_in_old]
-        base_ini = state.inits[b][pos_in_old]
-        sel = promo_valid[:, None]
-        cli = base_cli.at[promo_pos].set(
-            jnp.where(sel, dec_cli, base_cli[promo_pos]))
-        ini = base_ini.at[promo_pos].set(
-            jnp.where(sel, dec_ini, base_ini[promo_pos]))
-        if rpad:
-            cli = jnp.pad(cli, ((0, rpad), (0, 0)))
-            ini = jnp.pad(ini, ((0, rpad), (0, 0)))
-        clients_hot.append(cli)
-        inits_hot.append(ini)
-    clients_hot = _constrain_buckets(spec, mesh, clients_hot, stacked=True)
-    inits_hot = _constrain_buckets(spec, mesh, inits_hot, stacked=True)
+    with jax.named_scope("favas_promote"):
+        rpad = spec.stacked_rows - s_hot
+        promo_ids = members[promo_pos]
+        promo_rows = plan["promo_slab"] if host_cold else promo_ids
+        clients_hot, inits_hot = [], []
+        for b in range(spec.n_buckets):
+            dt = jnp.dtype(spec.bucket_dtypes[b])
+            enc_rows = jax.tree_util.tree_map(lambda p: p[promo_rows], cold[b])
+            dec_cli, dec_ini = codec.decode_pair(enc_rows, dt,
+                                                 shards=spec.shards(b),
+                                                 use_kernel=use_kernel)
+            base_cli = state.clients[b][pos_in_old]
+            base_ini = state.inits[b][pos_in_old]
+            sel = promo_valid[:, None]
+            cli = base_cli.at[promo_pos].set(
+                jnp.where(sel, dec_cli, base_cli[promo_pos]))
+            ini = base_ini.at[promo_pos].set(
+                jnp.where(sel, dec_ini, base_ini[promo_pos]))
+            if rpad:
+                cli = jnp.pad(cli, ((0, rpad), (0, 0)))
+                ini = jnp.pad(ini, ((0, rpad), (0, 0)))
+            clients_hot.append(cli)
+            inits_hot.append(ini)
+        clients_hot = _constrain_buckets(spec, mesh, clients_hot, stacked=True)
+        inits_hot = _constrain_buckets(spec, mesh, inits_hot, stacked=True)
 
     # 5. hot-set bookkeeping + batch rows (the credit clock advances for
     # hot clients only — cold clients are frozen, not merely unselected)
@@ -1190,11 +1213,8 @@ def _paged_round(spec: FlatSpec, state: EngineState, batch, *, cfg,
                                           cfg.quant_bits, mesh=mesh,
                                           use_kernel=use_kernel)
     elif cfg.quant_bits > 0:
-        inits_tree = unflatten_stacked(spec, inits_hot)
-        prog = quantize_tree(tree_map(jnp.subtract, trained_tree, inits_tree),
-                             cfg.quant_bits, k_q)
-        progress = _constrain_buckets(spec, mesh, flatten_stacked(spec, prog),
-                                      stacked=True)
+        progress = _tree_progress(spec, mesh, trained_tree, inits_hot, k_q,
+                                  cfg.quant_bits)
 
     # 8. aggregation + selected-client reset over the hot stacks
     alpha_p = pad_client_vec(spec, alpha, 1.0)
@@ -1249,6 +1269,9 @@ def _paged_round(spec: FlatSpec, state: EngineState, batch, *, cfg,
         "mean_steps": jnp.mean(q1.astype(jnp.float32)),
         "selected": jnp.sum(m),
         "stale_rounds": jnp.max(stale_new).astype(jnp.float32),
+        # only the s_max hot rows ran their R forward/backward steps
+        "live_steps": total_live.astype(jnp.int32),
+        "computed_steps": jnp.int32(s_hot * cfg.R),
     }
     if host_cold:
         return new_state, tuple(cold), metrics
@@ -1600,25 +1623,28 @@ class RoundEngine:
         move both to device. Returns ``(state_sans_pool, pool, uids, slab,
         plans)`` — see docs §13 and :mod:`repro.core.streaming`."""
         from repro.core import streaming
-        pool = state.cold
-        state = dataclasses.replace(state, cold=None)
-        _, plan = self._plan(state.key, state.stale, state.hot_ids,
-                             n_rounds=n_rounds, device_plane=device_plane)
-        plan = jax.device_get(plan)
-        slab_rows = streaming.chunk_slab_rows(self.spec, self.cfg, n_rounds)
-        uids, slab_plan = streaming.build_chunk_plan(plan,
-                                                     slab_rows=slab_rows)
-        slab_np = pool.gather(uids, slab_rows)
-        shardings = slab_shardings(self.spec, self.mesh)
-        slab = (jax.device_put(slab_np, shardings) if shardings is not None
-                else jax.device_put(slab_np))
-        plans = jax.tree_util.tree_map(jnp.asarray, slab_plan)
+        with jax.profiler.TraceAnnotation("favas_host_prologue"):
+            pool = state.cold
+            state = dataclasses.replace(state, cold=None)
+            _, plan = self._plan(state.key, state.stale, state.hot_ids,
+                                 n_rounds=n_rounds, device_plane=device_plane)
+            plan = jax.device_get(plan)
+            slab_rows = streaming.chunk_slab_rows(self.spec, self.cfg,
+                                                  n_rounds)
+            uids, slab_plan = streaming.build_chunk_plan(plan,
+                                                         slab_rows=slab_rows)
+            slab_np = pool.gather(uids, slab_rows)
+            shardings = slab_shardings(self.spec, self.mesh)
+            slab = (jax.device_put(slab_np, shardings)
+                    if shardings is not None else jax.device_put(slab_np))
+            plans = jax.tree_util.tree_map(jnp.asarray, slab_plan)
         return state, pool, uids, slab, plans
 
     def _host_epilogue(self, state: EngineState, pool, uids, slab):
         """Write the chunk's final slab rows back into the host pool and
         re-attach it to the state."""
-        pool.writeback(uids, jax.device_get(slab))
+        with jax.profiler.TraceAnnotation("favas_host_epilogue"):
+            pool.writeback(uids, jax.device_get(slab))
         return dataclasses.replace(state, cold=pool)
 
     def step(self, state: EngineState, batch):
@@ -1628,10 +1654,12 @@ class RoundEngine:
             state, pool, uids, slab, plans = self._host_prologue(
                 state, 1, device_plane=False)
             plan0 = jax.tree_util.tree_map(lambda x: x[0], plans)
-            state, slab, metrics = self._round_host(state, batch, slab,
-                                                    plan0)
+            with jax.profiler.TraceAnnotation("favas_step"):
+                state, slab, metrics = self._round_host(state, batch, slab,
+                                                        plan0)
             return self._host_epilogue(state, pool, uids, slab), metrics
-        return self._round(state, batch)
+        with jax.profiler.TraceAnnotation("favas_step"):
+            return self._round(state, batch)
 
     def run(self, state: EngineState, batches,
             n_rounds: Optional[int] = None):
@@ -1651,10 +1679,12 @@ class RoundEngine:
         if self.spec.paged and self.spec.cold_placement == "host":
             state, pool, uids, slab, plans = self._host_prologue(
                 state, T, device_plane=False)
-            state, slab, metrics = self._multi_host(state, slab, batches,
-                                                    plans)
+            with jax.profiler.TraceAnnotation("favas_run"):
+                state, slab, metrics = self._multi_host(state, slab, batches,
+                                                        plans)
             return self._host_epilogue(state, pool, uids, slab), metrics
-        return self._multi(state, batches)
+        with jax.profiler.TraceAnnotation("favas_run"):
+            return self._multi(state, batches)
 
     def run_device(self, state: EngineState, corpus, n_rounds: int):
         """A chunk of rounds on the DEVICE data plane: one superstep
@@ -1669,10 +1699,12 @@ class RoundEngine:
         if self.spec.paged and self.spec.cold_placement == "host":
             state, pool, uids, slab, plans = self._host_prologue(
                 state, n_rounds, device_plane=True)
-            state, slab, metrics = self._multi_device_host(
-                state, slab, plans, corpus, n_rounds=n_rounds)
+            with jax.profiler.TraceAnnotation("favas_run_device"):
+                state, slab, metrics = self._multi_device_host(
+                    state, slab, plans, corpus, n_rounds=n_rounds)
             return self._host_epilogue(state, pool, uids, slab), metrics
-        return self._multi_device(state, corpus=corpus, n_rounds=n_rounds)
+        with jax.profiler.TraceAnnotation("favas_run_device"):
+            return self._multi_device(state, corpus=corpus, n_rounds=n_rounds)
 
     def server_params(self, state: EngineState):
         return engine_server_params(self.spec, state)

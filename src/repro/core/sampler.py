@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils.profiling import scoped
+
 
 def make_lambdas(n: int, slow_fraction: float = 1.0 / 3.0,
                  lam_fast: float = 1.0 / 16.0, lam_slow: float = 0.5,
@@ -27,6 +29,7 @@ def make_lambdas(n: int, slow_fraction: float = 1.0 / 3.0,
     return rng.permutation(lam).astype(np.float32)
 
 
+@scoped("favas_sample")
 def sample_increments(key, lambdas) -> jnp.ndarray:
     """d_i ~ 1 + Geom0(lambda_i): support {1,2,...}, E[d] = 1/lambda."""
     u = jax.random.uniform(key, lambdas.shape, minval=1e-7, maxval=1.0)
@@ -34,6 +37,7 @@ def sample_increments(key, lambdas) -> jnp.ndarray:
     return jnp.maximum(d, 1)
 
 
+@scoped("favas_sample")
 def sample_selection_indices(key, n: int, s: int):
     """Uniform s-of-n without replacement, drawn in-jit via Gumbel top-s
     (exact uniform w/o replacement). Returns ``(idx (s,) int32, mask (n,)

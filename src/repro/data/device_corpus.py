@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils.profiling import scoped
+
 
 # ---------------------------------------------------------------------------
 # Index-sampling math + numpy mirrors
@@ -129,6 +131,7 @@ class DeviceCorpus:
         v = self.lengths if self.kind == "classification" else self.lo
         return v.shape[0]
 
+    @scoped("favas_sample")
     def sample_round_batch(self, key, n_steps: int, ids=None) -> Dict:
         """Draw one round's per-client microbatches ON DEVICE (jit/scan
         safe). Returns the same batch pytree the host plane ships:

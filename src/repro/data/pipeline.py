@@ -225,25 +225,27 @@ class BatchPrefetcher:
         """Next batch, blocking until the producer has one ready. Batches
         built before a producer failure are still served (FIFO); the error
         surfaces at its position in the stream."""
-        while True:
-            if self._n is not None and self._served >= self._n:
-                raise StopIteration
-            try:
-                b = self._q.get(timeout=0.1)
-            except queue.Empty:
-                if self._err is not None:
-                    err, self._err = self._err, None
-                    raise err
-                if not self._thread.is_alive():
-                    raise StopIteration from None
-                continue
-            if b is self._done:
-                if self._err is not None:
-                    err, self._err = self._err, None
-                    raise err
-                raise StopIteration
-            self._served += 1
-            return b
+        import jax
+        with jax.profiler.TraceAnnotation("favas_prefetch_wait"):
+            while True:
+                if self._n is not None and self._served >= self._n:
+                    raise StopIteration
+                try:
+                    b = self._q.get(timeout=0.1)
+                except queue.Empty:
+                    if self._err is not None:
+                        err, self._err = self._err, None
+                        raise err
+                    if not self._thread.is_alive():
+                        raise StopIteration from None
+                    continue
+                if b is self._done:
+                    if self._err is not None:
+                        err, self._err = self._err, None
+                        raise err
+                    raise StopIteration
+                self._served += 1
+                return b
 
     def __iter__(self):
         while True:

@@ -239,6 +239,7 @@ def favas_agg_pallas(server, clients, inits, alpha, mask, s: float,
             out_specs=pl.BlockSpec((1, TILE), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((1, Dp), server.dtype),
             interpret=interpret,
+            name="favas_agg",
         )(server.reshape(1, Dp), clients, inits, coef, maskc)
         return out.reshape(Dp)[:D]
 
@@ -262,6 +263,7 @@ def favas_agg_pallas(server, clients, inits, alpha, mask, s: float,
         out_shape=jax.ShapeDtypeStruct((1, Dp), server.dtype),
         scratch_shapes=[pltpu.VMEM((1, TILE), jnp.float32)],
         interpret=interpret,
+        name="favas_agg",
     )(server.reshape(1, Dp), clients, inits, coef, maskc)
     return out.reshape(Dp)[:D]
 
@@ -480,6 +482,7 @@ def favas_fused_pallas(server, clients, inits, alpha, mask, s: float,
                 jax.ShapeDtypeStruct((n, Dp), inits.dtype),
             ),
             interpret=interpret,
+            name="favas_fused",
         )(*operands)
         return srv.reshape(Dp)[:D], cli[:, :D], ini[:, :D]
 
@@ -559,6 +562,7 @@ def favas_fused_pallas(server, clients, inits, alpha, mask, s: float,
         scratch_shapes=[pltpu.VMEM((1, TILE), jnp.float32),
                         pltpu.VMEM((1, TILE), jnp.float32)],
         interpret=interpret,
+        name="favas_fused",
     )(*operands)
     return srv.reshape(Dp)[:D], cli[:n, :D], ini[:n, :D]
 
@@ -733,6 +737,7 @@ def favas_stream_pallas(server, clients, inits, alpha, mask, s: float,
             out_specs=srv_spec,
             out_shape=jax.ShapeDtypeStruct((1, Dp), server.dtype),
             interpret=interpret,
+            name="favas_stream",
         )(*operands)
         return srv.reshape(Dp)[:D]
 
@@ -786,5 +791,6 @@ def favas_stream_pallas(server, clients, inits, alpha, mask, s: float,
         out_shape=jax.ShapeDtypeStruct((1, Dp), server.dtype),
         scratch_shapes=[pltpu.VMEM((1, TILE), jnp.float32)],
         interpret=interpret,
+        name="favas_stream",
     )(*operands)
     return srv.reshape(Dp)[:D]

@@ -167,6 +167,7 @@ def luq_pallas(x, u_prune, u_round, bits: int, *, interpret: bool = False):
         out_specs=pl.BlockSpec((ROWS, COLS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, COLS), dtype),
         interpret=interpret,
+        name="luq",
     )(x2, up2, ur2, scale)
     return out.reshape(-1)[:D].reshape(orig_shape)
 
@@ -276,6 +277,7 @@ def luq_encode_pallas(x, u_prune, u_round, bits: int, *, shards: int = 1,
                                lambda i, c: (i, c)),
         out_shape=jax.ShapeDtypeStruct((rp, D * bits // 8), jnp.uint8),
         interpret=interpret,
+        name="luq_encode",
     )(xf, up, ur, lane_scales(scale_p))
     return {"codes": packed[:rows], "scale": scale}
 
@@ -311,5 +313,6 @@ def luq_decode_pallas(enc, bits: int, dtype, *, shards: int = 1,
         out_specs=pl.BlockSpec((ENC_ROWS, tile), lambda i, c: (i, c)),
         out_shape=jax.ShapeDtypeStruct((rp, D), jnp.dtype(dtype)),
         interpret=interpret,
+        name="luq_decode",
     )(codes, lane_scales(scale_p))
     return out[:rows]

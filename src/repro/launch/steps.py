@@ -278,7 +278,8 @@ def build_train_step(arch: str, mesh, fcfg: Optional[FavasConfig] = None,
         batch_sh = batch_shardings(batch_sds, mesh, leading_client_axis=True,
                                    leading_rounds_axis=rounds_per_step > 1)
     metrics_sh = {k: NamedSharding(mesh, P()) for k in
-                  ("loss", "mean_steps", "selected", "stale_rounds")}
+                  ("loss", "mean_steps", "selected", "stale_rounds",
+                   "live_steps", "computed_steps")}
     jitted = jax.jit(step, in_shardings=(state_sh, batch_sh),
                      out_shardings=(state_sh, metrics_sh), donate_argnums=(0,))
     return jitted, (state_sds, batch_sds), cfg

@@ -236,8 +236,11 @@ def run(args, cfg=None):
         """Materialize pending chunk metrics (ONE host sync per flush) and
         emit the per-round JSONL records the per-round loop used to write."""
         nonlocal pending
-        for start, W, m in pending:
-            host = {k: np.atleast_1d(np.asarray(v)) for k, v in m.items()}
+        with jax.profiler.TraceAnnotation("favas_fetch_metrics"):
+            fetched = [(start, W, {k: np.atleast_1d(np.asarray(v))
+                                   for k, v in m.items()})
+                       for start, W, m in pending]
+        for start, W, host in fetched:
             for j in range(W):
                 losses.append(float(host["loss"][j]))
                 logger.log(start + j + 1, loss=host["loss"][j],
@@ -267,29 +270,33 @@ def run(args, cfg=None):
             # chunk from the chunk-end state (the only state the host has)
             # and is labeled with THAT round number.
             need_var = rounds_done >= next_log
-            rate = f"{rounds_done/(time.time()-t0):.2f} it/s"
-            while rounds_done >= next_log:
+            if need_var:
+                # the fetch waits for the chunk, so the rate counts
+                # finished rounds, not enqueued ones
                 flush()
+                rate = f"{rounds_done/(time.time()-t0):.2f} it/s"
+            while rounds_done >= next_log:
                 window = losses[next_log - args.log_every:next_log]
                 line = f"round {next_log:5d} | loss {np.mean(window):.4f}"
                 if next_log == rounds_done:
                     # variance and throughput are measured at the chunk-end
                     # state/round — only printed on the line they belong to
-                    var = float(engine.variance(state))
+                    var = _variance(engine, state)
                     logger.log(rounds_done, client_variance=var)
                     line += f" | client-var {var:.3e} | {rate}"
                     need_var = False
                 print(line)
                 next_log += args.log_every
             if need_var:      # boundaries crossed mid-chunk only
-                var = float(engine.variance(state))
+                var = _variance(engine, state)
                 logger.log(rounds_done, client_variance=var)
                 print(f"round {rounds_done:5d} | client-var {var:.3e} | {rate}")
             if args.ckpt_dir and rounds_done >= next_ckpt:
                 # one snapshot per chunk (mid-chunk state never exists on
                 # the host); keep the cadence anchored to --ckpt-every
                 # multiples even when a chunk crosses several boundaries
-                save_engine_checkpoint(args.ckpt_dir, rounds_done, state)
+                with jax.profiler.TraceAnnotation("favas_checkpoint"):
+                    save_engine_checkpoint(args.ckpt_dir, rounds_done, state)
                 while next_ckpt <= rounds_done:
                     next_ckpt += args.ckpt_every
     finally:
@@ -299,6 +306,11 @@ def run(args, cfg=None):
     print(f"done: first-10 loss {np.mean(losses[:10]):.4f} -> "
           f"last-10 {np.mean(losses[-10:]):.4f}")
     return state, losses, engine
+
+
+def _variance(engine, state) -> float:
+    with jax.profiler.TraceAnnotation("favas_variance"):
+        return float(engine.variance(state))
 
 
 def main():

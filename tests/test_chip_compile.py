@@ -5,7 +5,8 @@ compiler refuses: unaligned or strided lane slices, blocks that break the
 (8, 128) rule, kernels that overrun VMEM. Each test here compiles one entry
 point for a v5e chip that is described, not attached, and asserts that the
 compiled program holds the Mosaic kernel (``tpu_custom_call``). Nothing
-runs, so nothing here is a result or a time.
+runs, so nothing here is a result or a time. Each kernel also carries its
+``pallas_call`` name, the name a profiler trace shows for it.
 
 Shapes: the streamed and fused rounds at the bucket width of the Qwen3-4B
 configuration ``chip_smoke.py`` trains (depth 1, a quarter of the
@@ -20,6 +21,7 @@ workers that do not run this file never load the TPU library.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,9 +76,13 @@ def smoke_width():
     return spec.bucket_padded[0]
 
 
-def _compile(fn, *args):
+def _compile(name, fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    kernels = re.findall(
+        r'%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text)
+    assert kernels and all(re.fullmatch(rf"{name}(\.\d+)?", k)
+                           for k in kernels), kernels
     return text
 
 
@@ -94,34 +100,37 @@ def _width(smoke_width, n):
 
 
 @pytest.mark.parametrize("n", [2, 64])
-@pytest.mark.parametrize("kernel", [favas_stream_pallas, favas_fused_pallas],
+@pytest.mark.parametrize("kernel,name",
+                         [(favas_stream_pallas, "favas_stream"),
+                          (favas_fused_pallas, "favas_fused")],
                          ids=["stream", "fused"])
-def test_round_kernel_compiles(one_chip, smoke_width, kernel, n):
+def test_round_kernel_compiles(one_chip, smoke_width, kernel, name, n):
     args = _round_args(one_chip, n, _width(smoke_width, n))
-    _compile(lambda *a: kernel(*a, 1.0), *args)
+    _compile(name, lambda *a: kernel(*a, 1.0), *args)
 
 
 @pytest.mark.parametrize("n", [2, 64])
 def test_round_kernel_bf16_compiles(one_chip, smoke_width, n):
     args = _round_args(one_chip, n, _width(smoke_width, n), jnp.bfloat16)
-    _compile(lambda *a: favas_stream_pallas(*a, 1.0), *args)
+    _compile("favas_stream", lambda *a: favas_stream_pallas(*a, 1.0), *args)
 
 
 def test_agg_kernel_compiles(one_chip):
-    _compile(lambda *a: favas_agg_pallas(*a, 1.0),
+    _compile("favas_agg", lambda *a: favas_agg_pallas(*a, 1.0),
              *_round_args(one_chip, 64, CODEC_D))
 
 
 def test_luq_kernel_compiles(one_chip):
     x = jax.ShapeDtypeStruct((CODEC_D,), jnp.float32, sharding=one_chip)
-    _compile(lambda x, u, v: luq_pallas(x, u, v, 4), x, x, x)
+    _compile("luq", lambda x, u, v: luq_pallas(x, u, v, 4), x, x, x)
 
 
 @pytest.mark.parametrize("shards", SHARDS)
 @pytest.mark.parametrize("bits", BITS)
 def test_luq_encode_compiles(one_chip, bits, shards):
     x = jax.ShapeDtypeStruct((64, CODEC_D), jnp.float32, sharding=one_chip)
-    _compile(lambda x, u, v: luq_encode_pallas(x, u, v, bits, shards=shards),
+    _compile("luq_encode",
+             lambda x, u, v: luq_encode_pallas(x, u, v, bits, shards=shards),
              x, x, x)
 
 
@@ -135,8 +144,8 @@ def _codes(one_chip, rows, bits, shards):
 @pytest.mark.parametrize("shards", SHARDS)
 @pytest.mark.parametrize("bits", BITS)
 def test_luq_decode_compiles(one_chip, bits, shards):
-    _compile(lambda e: luq_decode_pallas(e, bits, jnp.float32,
-                                         shards=shards),
+    _compile("luq_decode",
+             lambda e: luq_decode_pallas(e, bits, jnp.float32, shards=shards),
              _codes(one_chip, 64, bits, shards))
 
 
@@ -147,5 +156,5 @@ def test_codes_in_stream_compiles(one_chip, bits, shards, n):
     def step(w, c, i, a, m, e):
         return favas_stream_pallas(w, c, i, a, m, 1.0, progress_codes=e,
                                    progress_bits=bits, progress_shards=shards)
-    _compile(step, *_round_args(one_chip, n, CODEC_D),
+    _compile("favas_stream", step, *_round_args(one_chip, n, CODEC_D),
              _codes(one_chip, n, bits, shards))
