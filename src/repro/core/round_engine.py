@@ -615,12 +615,12 @@ def stream_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
                          use_kernel: Optional[bool] = None):
     """One bucket's STREAMED aggregation (docs/architecture.md §13):
     the :func:`fused_bucket_update` dispatch contract, returning ONLY the
-    new server vector. The caller applies the selected-client reset as a
-    churn-bounded scatter of this row into the donated client/init
-    buffers — unselected rows are never rewritten, so per-bucket round
-    traffic drops from ~2R+2W to 1R (+ O(s * Dp) scatter writes) per
-    resident byte. Bit-identical server to ``fused_bucket_update`` per
-    dispatch path."""
+    new server vector. The caller writes this row into the selected rows
+    of the donated client/init buffers (:func:`_streamed_reset`) — on
+    large stacks by a churn-bounded scatter that never rewrites the
+    unselected rows, so per-bucket round traffic drops from ~2R+2W to 1R
+    (+ O(s * Dp) scatter writes) per resident byte. Bit-identical server
+    to ``fused_bucket_update`` per dispatch path."""
     return _bucket_dispatch(
         favas_stream_flat, spec, b, server_b, trained_b, inits_b, alpha_p,
         mask_p, s, progress_b=progress_b, progress_codes_b=progress_codes_b,
@@ -628,19 +628,39 @@ def stream_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
         use_kernel=use_kernel, stacked_outputs=False)
 
 
+def reset_path(rows: int, s: int, dtype) -> str:
+    """How :func:`_streamed_reset` writes ``s`` rows into a ``(rows, D)``
+    stack of ``dtype``: ``"masked"`` (one elementwise pass over the whole
+    stack) or ``"scatter"`` (s in-place row updates). In the TPU's
+    ``(tile_rows, 128)`` tiling a client row is one sublane of every tile
+    it crosses, so each unaligned row update rewrites all of those tiles:
+    once ``rows <= s * tile_rows`` the s updates cost at least one pass
+    over the stack each, and a single masked pass is cheaper
+    (docs/architecture.md §13)."""
+    tile_rows = 8 * 4 // jnp.dtype(dtype).itemsize
+    return "masked" if rows <= s * tile_rows else "scatter"
+
+
 @scoped("favas_reset")
 def _streamed_reset(spec: FlatSpec, mesh, bufs, sel_idx, rows):
-    """Churn-bounded selected-client reset: scatter each bucket's new server
-    row into the ``sel_idx`` positions of the (donated) state buffers.
-    ``rows`` is the per-bucket new-server vector list. XLA performs the
-    scatter in place on donated inputs, so unselected rows are never
-    rewritten (the write-traffic audit in launch/roofline.py pins this).
-    Bit-exact vs the fused reset: the mask is exactly the indicator of
+    """Selected-client reset: write each bucket's new server row into the
+    ``sel_idx`` positions of the (donated) state buffers. ``rows`` is the
+    per-bucket new-server vector list. Large stacks take a churn-bounded
+    scatter, which XLA performs in place so unselected rows are never
+    rewritten (the write-traffic audit in launch/roofline.py pins this);
+    small stacks take one masked pass (:func:`reset_path`). Both are
+    bit-exact vs the fused reset: the mask is exactly the indicator of
     ``sel_idx`` and the fused ``m*s_new + (1-m)*x`` blend is ``x`` (exact
     f32 round-trip) off-selection and ``s_new.astype(dtype)`` — the
-    scattered row — on it."""
-    out = [buf.at[sel_idx].set(row.astype(buf.dtype))
-           for buf, row in zip(bufs, rows)]
+    written row — on it."""
+    out = []
+    for buf, row in zip(bufs, rows):
+        row = row.astype(buf.dtype)
+        if reset_path(buf.shape[0], sel_idx.shape[0], buf.dtype) == "masked":
+            hit = (jnp.arange(buf.shape[0])[:, None] == sel_idx[None, :]).any(1)
+            out.append(jnp.where(hit[:, None], row[None, :], buf))
+        else:
+            out.append(buf.at[sel_idx].set(row))
     return _constrain_buckets(spec, mesh, out, stacked=True)
 
 
@@ -897,8 +917,9 @@ def engine_round(spec: FlatSpec, state: EngineState, batch=None, *, cfg,
         paged spec, gathers corpus rows for the hot working set only).
       schedule: "streamed" (default, docs/architecture.md §13) aggregates
         with the single-sweep :func:`stream_bucket_update` and resets the
-        s selected rows by a churn-bounded scatter into the donated
-        buffers (~1R+1W per resident byte, no pass-through rewrites);
+        s selected rows of the donated buffers (:func:`_streamed_reset`:
+        a churn-bounded scatter, ~1R+1W per resident byte with no
+        pass-through rewrites, or one masked pass on small stacks);
         "two_sweep" keeps the historical fused aggregation+reset kernel
         (~2R+2W). The two schedules are BIT-EXACT — the mask is exactly
         the indicator of the Gumbel top-s index set — so the knob only
@@ -984,9 +1005,9 @@ def engine_round(spec: FlatSpec, state: EngineState, batch=None, *, cfg,
     m_p = pad_client_vec(spec, m, 0.0)
     server_new, clients_new, inits_new = [], [], []
     if schedule == "streamed":
-        # §13: single-sweep aggregation, then ONE churn-bounded scatter of
-        # the new server row into the s selected rows of the donated
-        # trained/init buffers — unselected rows are never rewritten
+        # §13: single-sweep aggregation, then ONE reset writing the new
+        # server row into the s selected rows of the donated trained/init
+        # buffers (scatter, or a masked pass on small stacks)
         for b in range(spec.n_buckets):
             server_new.append(stream_bucket_update(
                 spec, b, state.server[b], trained[b], state.inits[b],
@@ -1223,7 +1244,8 @@ def _paged_round(spec: FlatSpec, state: EngineState, batch, *, cfg,
     if schedule == "streamed":
         # §13: every selected client is hot (engine_init enforces
         # s <= s_max), so m_hot carries exactly s ones and the nonzero
-        # fill value is never consumed. Scatter replaces the second sweep.
+        # fill value is never consumed. The reset replaces the second
+        # sweep.
         sel_pos = jnp.nonzero(m_hot > 0, size=s, fill_value=0)[0]
         for b in range(spec.n_buckets):
             server_new.append(stream_bucket_update(
@@ -1539,6 +1561,11 @@ class RoundEngine:
         self.use_kernel = use_kernel
         self.quant_fused = quant_fused
         self.schedule = schedule
+        # how the streamed round's reset writes each bucket's client and
+        # init stacks (reset_path); fixed per compile, empty on two_sweep
+        self.reset_paths = () if schedule == "two_sweep" else tuple(
+            reset_path(self.spec.stacked_rows, cfg.s_selected, dt)
+            for dt in self.spec.bucket_dtypes)
         self._round = jax.jit(
             functools.partial(engine_round, self.spec, cfg=self.cfg,
                               loss_fn=self.loss_fn, lambdas=self.lambdas,

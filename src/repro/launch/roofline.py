@@ -187,10 +187,13 @@ def pass_through_copies(hlo_text: str, *, rows: int, min_cols: int = 128,
     outputs are full ``(n, D)`` elementwise fusions (the ``m*s_new +
     (1-m)*x`` blend), ~1 extra read + 1 extra write per resident byte.
     Returns ``(output_name, defining_opcode, dims)`` per violation; a
-    compiled streamed round must return ``[]`` (pinned in
-    tests/test_streaming.py beside the ``dense_materializations`` gate
-    this mirrors). ``rows`` is the client-stack row count (n padded, or
-    s_max-stack rows for a paged round)."""
+    compiled streamed round whose stacks take the scatter reset must
+    return ``[]``, and one whose stacks are small enough for the masked
+    reset (``round_engine.reset_path``) flags exactly those passes (both
+    pinned in tests/test_streaming.py beside the
+    ``dense_materializations`` gate this mirrors). ``rows`` is the
+    client-stack row count (n padded, or s_max-stack rows for a paged
+    round)."""
     comps: Dict[str, List[str]] = {}
     entry = cur = None
     for ln in hlo_text.splitlines():

@@ -24,14 +24,18 @@ What is proven:
   BatchPrefetcher contract: strict order, errors surface in stream
   position, hardened close.
 * **write-traffic regression gate** — the compiled streamed round emits
-  ZERO full (rows, D) client/init rewrites (``roofline.pass_through_copies``
-  over the ENTRY root; two-sweep flags exactly its two blend fusions), and
+  ZERO full (rows, D) client/init rewrites where its stacks take the
+  scatter reset (``roofline.pass_through_copies`` over the ENTRY root;
+  two-sweep flags exactly its two blend fusions), exactly the two masked
+  reset passes and no per-row loop where they are small
+  (``round_engine.reset_path``, itself pinned bit-exact to the scatter), and
   the fused round's "bytes accessed" drops >= 1.4x vs two-sweep at
   n=1024, D=2^20 (AOT-compiled, never executed) — the §13 acceptance gate.
 * **tier accounting** — ``engine_resident_bytes_by_tier`` splits device vs
   host bytes: host pools never count against the device budget.
 """
 import os
+import re
 import subprocess
 import sys
 import threading
@@ -122,16 +126,24 @@ def _cold_bytes(cold):
 # schedule parity: streamed (default) == two_sweep, bit for bit
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["fp32", "bf16"])
-@pytest.mark.parametrize("qb,qf", [(0, False), (4, False), (4, True)],
-                         ids=["plain", "quant4", "quant4_fused"])
-def test_dense_streamed_bit_exact_vs_two_sweep(dtype, qb, qf):
+_QUANT = [(0, False, "plain"), (4, False, "quant4"), (4, True, "quant4_fused")]
+_DTYPES = [(jnp.float32, "fp32"), (jnp.bfloat16, "bf16")]
+
+
+# n=7 resets every stack with the masked pass, n=64 with the row scatter
+@pytest.mark.parametrize("dtype,qb,qf,n", [
+    pytest.param(dt, qb, qf, n, id=f"{qid}-{did}{sfx}")
+    for n, sfx in [(7, ""), (64, "-n64")]
+    for qb, qf, qid in _QUANT for dt, did in _DTYPES])
+def test_dense_streamed_bit_exact_vs_two_sweep(dtype, qb, qf, n):
     T = 5
-    e1, fcfg, params = _engine(dtype, quant_bits=qb, n=7, quant_fused=qf)
-    e2, _, _ = _engine(dtype, quant_bits=qb, n=7, quant_fused=qf,
+    e1, fcfg, params = _engine(dtype, quant_bits=qb, n=n, quant_fused=qf)
+    e2, _, _ = _engine(dtype, quant_bits=qb, n=n, quant_fused=qf,
                        schedule="two_sweep")
     assert e1.schedule == "streamed"        # the default
+    path = "masked" if n == 7 else "scatter"
+    assert e1.reset_paths == (path,) * e1.spec.n_buckets
+    assert e2.reset_paths == ()
     key = jax.random.PRNGKey(3)
     s1, m1 = e1.run(e1.init_state(params, key), _batches(fcfg, T))
     s2, m2 = e2.run(e2.init_state(params, key), _batches(fcfg, T))
@@ -154,6 +166,35 @@ def test_paged_streamed_bit_exact_vs_two_sweep(cold_bits):
     _assert_states_equal(s1, s2)
     _assert_metrics_equal(m1, m2)
     np.testing.assert_array_equal(_cold_bytes(s1.cold), _cold_bytes(s2.cold))
+
+
+@pytest.mark.parametrize("rows,s,dtype,sel,path", [
+    (8, 2, jnp.float32, [5, 2], "masked"),
+    (16, 2, jnp.bfloat16, [0, 15], "masked"),
+    (64, 2, jnp.float32, [63, 17], "scatter"),
+    (64, 4, jnp.float32, [3, 40, 9, 62], "scatter"),
+    (64, 2, jnp.bfloat16, [31, 32], "scatter"),
+    # the paged round pads its nonzero() positions with row 0
+    (8, 3, jnp.float32, [6, 0, 0], "masked"),
+], ids=["f32-8-masked", "bf16-16-masked", "f32-64-s2-scatter",
+        "f32-64-s4-scatter", "bf16-64-scatter", "f32-8-duplicate"])
+def test_streamed_reset_matches_scatter(rows, s, dtype, sel, path):
+    """The reset writes exactly what ``buf.at[sel_idx].set(row)`` writes,
+    bit for bit, on whichever path ``reset_path`` picks for the stack."""
+    assert round_engine.reset_path(rows, s, dtype) == path
+    D = 384
+    rng = np.random.default_rng(rows * 10 + s)
+    buf = jnp.asarray(rng.standard_normal((rows, D)), dtype)
+    inits = jnp.asarray(rng.standard_normal((rows, D)), dtype)
+    row = jnp.asarray(rng.standard_normal(D), jnp.float32)
+    sel_idx = jnp.asarray(sel, jnp.int32)
+    out = jax.jit(lambda b, i, r, x: round_engine._streamed_reset(
+        None, None, (b, i), x, (r, r)))(buf, inits, row, sel_idx)
+    for got, x in zip(out, (buf, inits)):
+        want = x.at[sel_idx].set(row.astype(dtype))
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 def test_engine_round_rejects_unknown_schedule():
@@ -455,6 +496,31 @@ def test_streamed_round_no_pass_through_rewrites():
     assert len(flagged) == 2, flagged          # clients + inits full blends
     assert pass_through_copies(compiled("streamed").as_text(),
                                rows=n, min_cols=1024) == []
+
+
+def test_streamed_round_small_stack_masked_reset():
+    """At n=8, s=2 each stack is reset by one masked pass, not by XLA's
+    per-row loop: the compiled streamed round holds no while loop,
+    scatter or dynamic-update-slice over an (8, Dp) stack, and the
+    pass-through audit flags exactly the two masked passes (clients +
+    inits)."""
+    n = 8
+    w = jnp.asarray(np.linspace(-1, 1, 48 * 40).reshape(48, 40), jnp.float32)
+    params = {"w": w}
+    fcfg = FavasConfig(n_clients=n, s_selected=2, local_steps=2, eta=0.1)
+    eng = round_engine.RoundEngine(
+        params, fcfg, _loss, lambdas=jnp.asarray(client_lambdas(fcfg)))
+    assert eng.reset_paths == ("masked",)
+    st = eng.init_state(params, jax.random.PRNGKey(0))
+    text = eng._round.lower(
+        st, {"t": jnp.zeros((n, fcfg.R), jnp.float32)}).compile().as_text()
+    stack = f"[{n},{eng.spec.bucket_padded[0]}]"
+    op_re = re.compile(r"=\s*(.*?)\s(while|scatter|dynamic-update-slice)\(")
+    writes = [m.group(2) for m in map(op_re.search, text.splitlines())
+              if m and stack in m.group(1)]
+    assert writes == [], writes
+    flagged = pass_through_copies(text, rows=n, min_cols=1024)
+    assert len(flagged) == 2, flagged
 
 
 def test_fused_round_traffic_reduction():

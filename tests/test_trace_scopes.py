@@ -107,7 +107,8 @@ def test_scopes_change_only_metadata(kind, monkeypatch):
                         lambda name: contextlib.nullcontext())
     eng, _, state = _engine(**ROUNDS[kind])      # a fresh jit cache
     plain = _compiled_text(eng, state, corpus)
-    assert "/favas_" not in plain
+    # op_name alone: the debug tables may list kernels/favas_agg.py
+    assert "/favas_" not in "\n".join(re.findall(r'op_name="([^"]*)"', plain))
     assert _program(scoped) == _program(plain)
 
 
