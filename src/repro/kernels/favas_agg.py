@@ -22,7 +22,9 @@ total (1.29 MiB with the explicit-progress operand), comfortably inside
 ~16 MiB VMEM even with double buffering. ``fused_block_vmem_bytes`` computes
 this number from the declared block shapes; tests pin it under 2 MiB for
 the production shape (n=1024, D=2^20). The resident small-n kernels keep the
-PR-1 budget: (2n+1)*TILE*4B in + out ≈ 2.1 MiB at n=64.
+PR-1 budget: (2n+1)*TILE*4B in + out ≈ 2.1 MiB at n=64 — except the plain
+streamed kernel, whose lane blocks widen to fill ``STREAM_VMEM_BUDGET``
+(``stream_width``, ``stream_block_vmem_bytes``).
 
 Grid schedule of the tiled fused kernel, for each lane tile i (outer grid
 dim, "arbitrary" sequential semantics):
@@ -149,6 +151,44 @@ def fused_block_vmem_bytes(n: int, dtype, *, progress: bool = False,
     if double_buffered:
         inputs, outputs = 2 * inputs, 2 * outputs
     return inputs + outputs + scratch
+
+
+STREAM_VMEM_BUDGET = 12 << 20  # of a v5e kernel's 16 MiB of scoped VMEM
+STREAM_MAX_WIDTH = 1 << 15      # lanes per block: wider gains nothing
+
+
+def stream_block_vmem_bytes(n: int, dtype, width: int) -> int:
+    """VMEM footprint of the resident streamed kernel (``n`` rows, no
+    transmitted progress) at lane width ``width``: the pipeline's two
+    copies of the (n, width) client and init blocks (rows padded to the
+    dtype's sublane tiling), of the (1, width) server and new-server rows
+    (one sublane, as the (1, D) row is tiled in HBM; at most 4 bytes a
+    lane) and of the (n, 1) alpha and mask columns (lanes padded to 128);
+    and the f32 values Mosaic keeps in VMEM for the sum, at most two
+    (rows padded to 8, width) blocks and one 8-sublane block for the
+    total. A compile for a described v5e reports no more scoped VMEM than
+    this (tests/test_chip_compile.py)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    tile_rows = 8 * 4 // itemsize
+    padded = -(-n // tile_rows) * tile_rows
+    stacks = 2 * 2 * padded * width * itemsize
+    rows = 2 * 2 * width * 4
+    cols = 2 * 2 * -(-n // 8) * 8 * LANES * 4
+    sums = (2 * -(-n // 8) * 8 + 8) * width * 4
+    return stacks + rows + cols + sums
+
+
+def stream_width(n: int, dtype, D: int) -> int:
+    """Lane width of the resident streamed kernel's blocks: the widest
+    multiple of TILE up to ``STREAM_MAX_WIDTH`` (and no wider than D
+    rounded up to TILE) whose blocks fit ``STREAM_VMEM_BUDGET``, and never
+    below TILE. The last block may be partial: Pallas reads unspecified
+    lanes past the array's end and drops their writes."""
+    fixed = stream_block_vmem_bytes(n, dtype, 0)
+    per_lane = stream_block_vmem_bytes(n, dtype, 1) - fixed
+    width = min(STREAM_MAX_WIDTH, -(-D // TILE) * TILE,
+                (STREAM_VMEM_BUDGET - fixed) // per_lane)
+    return max(TILE, width // TILE * TILE)
 
 
 # ---------------------------------------------------------------------------
@@ -581,6 +621,13 @@ def favas_fused_pallas(server, clients, inits, alpha, mask, s: float,
 # unselected rows are never read for the reset nor rewritten. Steady-state
 # traffic drops to 1R per resident byte + O(s*D) scatter writes.
 #
+# Lane width: a TILE-wide grid step moves too little for HBM to set its
+# pace (on a TPU v5e, ~0.4 us a step for (8, TILE) f32 blocks, against
+# ~0.18 us of transfer at 819 GB/s). The resident kernel without
+# transmitted progress therefore reads ``stream_width`` lanes a step, a
+# partial last block covering a D that no wide block divides; per-lane
+# arithmetic, and so every bit of the result, is the same at any width.
+#
 # Bit-exactness contract (why the split loses nothing): the selection mask
 # is exactly the 0/1 indicator of the Gumbel top-s index set, so the fused
 # reset `m*s_new + (1-m)*x` is `x` to the bit for unselected rows and
@@ -661,7 +708,9 @@ def favas_stream_pallas(server, clients, inits, alpha, mask, s: float,
     returns ONLY the (D,) new server vector: the caller applies the
     selected-client reset as a churn-bounded scatter of this row into the
     donated state buffers (``core.round_engine.stream_bucket_update``).
-    One HBM read per resident client byte, ~zero client-buffer writes."""
+    One HBM read per resident client byte, ~zero client-buffer writes;
+    lane blocks ``stream_width`` wide on the resident path without
+    transmitted progress, TILE wide otherwise."""
     n, D = clients.shape
     ct = client_tile or CLIENT_TILE
     pad = (-D) % TILE
@@ -697,9 +746,11 @@ def favas_stream_pallas(server, clients, inits, alpha, mask, s: float,
     if n <= ct:                                   # whole client axis resident
         alphac = jnp.maximum(alpha.astype(jnp.float32), 1e-9).reshape(n, 1)
         maskc = mask.astype(jnp.float32).reshape(n, 1)
-        row_spec = pl.BlockSpec((n, TILE), lambda i: (0, i))
+        width = (stream_width(n, clients.dtype, Dp)
+                 if progress is None and codes is None else TILE)
+        row_spec = pl.BlockSpec((n, width), lambda i: (0, i))
         scalar_spec = pl.BlockSpec((n, 1), lambda i: (0, 0))
-        srv_spec = pl.BlockSpec((1, TILE), lambda i: (0, i))
+        srv_spec = pl.BlockSpec((1, width), lambda i: (0, i))
         if codes is not None:
             def kernel(server_ref, clients_ref, inits_ref, codes_ref,
                        pscale_ref, alpha_ref, mask_ref, srv_out_ref):
@@ -732,7 +783,7 @@ def favas_stream_pallas(server, clients, inits, alpha, mask, s: float,
                         alphac, maskc)
         srv = pl.pallas_call(
             kernel,
-            grid=(Dp // TILE,),
+            grid=(pl.cdiv(Dp, width),),
             in_specs=in_specs,
             out_specs=srv_spec,
             out_shape=jax.ShapeDtypeStruct((1, Dp), server.dtype),

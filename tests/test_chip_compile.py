@@ -10,11 +10,14 @@ runs, so nothing here is a result or a time. Each kernel also carries its
 
 Shapes: the streamed and fused rounds at the bucket width of the Qwen3-4B
 configuration ``chip_smoke.py`` trains (depth 1, a quarter of the
-vocabulary), from ``jax.eval_shape`` on its ``make_flat_spec``. At n=64 the
+vocabulary), from ``jax.eval_shape`` on its ``make_flat_spec``; the
+streamed kernel's wide lane blocks at the bucket of the benchmark's
+Mamba2-1.3B fan-in cell (8 rows, 52,066,304 f32 lanes). At n=64 the
 client stacks of that width need about 95 GB, six times the chip's HBM,
 which the compiler refuses; the n=64 cases compile a sixteenth of the width
-instead — the kernels' block shapes do not depend on D. The LUQ codec and
-the codes-in stream compile at D = 2^20 for bits {2, 4, 8} x shards {1, 4}.
+instead — the block shapes do not depend on D once it spans a block. The
+LUQ codec and the codes-in stream compile at D = 2^20 for bits {2, 4, 8} x
+shards {1, 4}.
 
 The topology is described inside a module fixture (never at import), so
 workers that do not run this file never load the TPU library.
@@ -26,13 +29,22 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax._src.lib import xla_client
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.favas_agg import (TILE, favas_agg_pallas,
-                                     favas_fused_pallas, favas_stream_pallas)
+from repro.kernels import favas_agg
+from repro.kernels.favas_agg import (STREAM_VMEM_BUDGET, TILE,
+                                     favas_agg_pallas, favas_fused_pallas,
+                                     favas_stream_pallas,
+                                     stream_block_vmem_bytes, stream_width)
 from repro.kernels.luq import luq_decode_pallas, luq_encode_pallas, luq_pallas
 
 CODEC_D = 1 << 20
+CELL_WIDTH = 52_066_304    # padded f32 bucket of the mamba2-1.3b fan-in cell
+# how bench/metrics/agg_kernel_ms.py finds the streamed kernel in a trace:
+# a Pallas call mapping the (1, D) server row, its first operand, to a new
+# (1, D) row
+SIGNATURE = re.compile(r"= f32\[1,(\d+)\]\{[^}]*\} custom-call\(f32\[1,\1\]\{")
 BITS = [2, 4, 8]
 SHARDS = [1, 4]
 
@@ -158,3 +170,39 @@ def test_codes_in_stream_compiles(one_chip, bits, shards, n):
                                    progress_bits=bits, progress_shards=shards)
     _compile("favas_stream", step, *_round_args(one_chip, n, CODEC_D),
              _codes(one_chip, n, bits, shards))
+
+
+@pytest.mark.parametrize("n", [8, 32])
+def test_wide_stream_kernel_compiles(one_chip, n, monkeypatch):
+    """The resident streamed kernel's wide lane blocks at the fan-in
+    cell's bucket (n = 8) and at a sixteenth of it for n = 32, where the
+    blocks narrow: one ``favas_stream`` kernel whose event keeps the
+    server-row signature the benchmark reads, a partial last block
+    (D / TILE is prime), no HBM temporary beyond the TILE-wide kernel's,
+    and no more VMEM than ``stream_block_vmem_bytes`` models."""
+    D = CELL_WIDTH if n == 8 else CELL_WIDTH // 16 // TILE * TILE
+    width = stream_width(n, jnp.float32, D)
+    assert width > TILE and D % width
+    args = _round_args(one_chip, n, D)
+    step = jax.jit(lambda w, c, i, a, m: favas_stream_pallas(
+        w, c, i, a, m, 2.0))
+    wide = step.lower(*args).compile()
+    monkeypatch.setattr(favas_agg, "STREAM_MAX_WIDTH", TILE)
+    narrow = jax.jit(lambda w, c, i, a, m: favas_stream_pallas(
+        w, c, i, a, m, 2.0)).lower(*args).compile()
+    assert (wide.memory_analysis().temp_size_in_bytes
+            <= narrow.memory_analysis().temp_size_in_bytes)
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    text = wide.runtime_executable().hlo_modules()[0].to_string(opts)
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 1, calls
+    assert re.search(r"%favas_stream(\.\d+)? = ", calls[0]), calls[0]
+    match = SIGNATURE.search(calls[0])
+    assert match and int(match.group(1)) == D, calls[0][:200]
+    # the VMEM the compiler gives the kernel is inside the modelled budget
+    used = re.search(r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+                     r'"offset":"0","size":"(\d+)"', calls[0])
+    assert int(used.group(1)) <= stream_block_vmem_bytes(
+        n, jnp.float32, width) <= STREAM_VMEM_BUDGET

@@ -90,9 +90,9 @@ def _trees_equal(a, b):
                                       np.asarray(y, np.float32))
 
 
-def _setup(n, dtype, *, quant_bits=0):
+def _setup(n, dtype, *, quant_bits=0, params=None):
     mesh = make_model_mesh(8)
-    params = make_params(dtype)
+    params = make_params(dtype) if params is None else params
     fcfg = FavasConfig(n_clients=n, s_selected=min(3, n), local_steps=2,
                        eta=0.1, quant_bits=quant_bits)
     lambdas = jnp.asarray(client_lambdas(fcfg))
@@ -169,6 +169,39 @@ def test_sharded_kernel_path_bit_exact(n):
                  round_engine.engine_server_params(spec_r, st_r))
     _trees_equal(round_engine.unflatten_stacked(spec_s, st_s.clients),
                  round_engine.unflatten_stacked(spec_r, st_r.clients))
+
+
+@needs8
+def test_sharded_wide_stream_kernel_bit_exact(monkeypatch):
+    """The plain streamed round's kernel on the mesh with lane blocks wider
+    than TILE: each shard's f32 bucket spans 3 TILEs and the blocks 2, so
+    every shard runs a partial last block. shard_map + interpret kernel vs
+    the single-device kernel (whose one segment is blocked differently)
+    and vs TILE-wide blocks on the mesh, bit for bit."""
+    from repro.kernels import favas_agg
+    params = make_params(jnp.float32)
+    params["embed"]["table"] = jnp.asarray(
+        np.linspace(-1.0, 1.0, 640 * 64).reshape(640, 64), jnp.float32)
+
+    def run(cap):
+        monkeypatch.setattr(favas_agg, "STREAM_MAX_WIDTH", cap)
+        (mesh, _, fcfg, lambdas, spec_s, spec_r,
+         st_s, st_r, batch, _) = _setup(7, jnp.float32, params=params)
+        assert [spec_s.bucket_shard_padded[b] for b in range(spec_s.n_buckets)
+                if spec_s.shards(b) == 8] == [3 * favas_agg.TILE]
+        step_s, step_r = _steps(spec_s, spec_r, mesh, fcfg, lambdas, True)
+        for _ in range(2):
+            st_s, _ = step_s(st_s, batch)
+            st_r, _ = step_r(st_r, batch)
+        return spec_s, st_s, spec_r, st_r
+
+    spec_s, wide, spec_r, single = run(2 * favas_agg.TILE)
+    _, narrow, _, _ = run(favas_agg.TILE)
+    for other, spec in ((single, spec_r), (narrow, spec_s)):
+        _trees_equal(round_engine.engine_server_params(spec_s, wide),
+                     round_engine.engine_server_params(spec, other))
+        _trees_equal(round_engine.unflatten_stacked(spec_s, wide.clients),
+                     round_engine.unflatten_stacked(spec, other.clients))
 
 
 @needs8
