@@ -1,17 +1,20 @@
-"""Kernel microbenchmarks for the FAVAS round hot path.
+"""Kernel microbenchmarks for the FAVAS round hot path (a CPU design aid;
+device numbers come from ``bench/`` on the chip).
 
-Measures the REAL round aggregation path the engine runs
-(``favas_fused_ref`` — aggregation + selected-client reset in one
-expression, what ``core/round_engine.py`` executes on CPU and what the
-Pallas kernel streams on TPU) against the seed's unfused multi-pass
-arithmetic (eq. 3 msgs, line-10 sum, two reset sweeps as separate
-full-buffer passes). A client-count sweep (n in {64, 256, 1024, 4096},
-constant n*D resident client elements) records fused-vs-seed bytes moved and
-throughput at production federation sizes — the regime the tiled
-client-axis kernel exists for. Also validates the multi-output Pallas
-kernel in interpret mode at a small resident shape AND a tiled
-(n > CLIENT_TILE) shape (structural check; interpret-mode *timing* is
-meaningless — TPU is the target).
+Measures the REAL round step the engine runs
+(``round_engine.aggregate_and_reset`` — the aggregation, then the new
+server row written into the selected rows; on CPU the jnp oracle
+``favas_stream_ref``, on TPU the ``favas_stream`` Pallas kernel) against
+the seed's unfused multi-pass arithmetic (eq. 3 msgs, line-10 sum, two
+reset sweeps as separate full-buffer passes). A client-count sweep (n in
+{64, 256, 1024, 4096}, constant n*D resident client elements) records
+step-vs-seed bytes moved and throughput at production federation sizes —
+the regime the tiled client-axis kernel exists for. Also validates the
+step on the Pallas kernel in interpret mode at a small resident shape AND
+a tiled (n > CLIENT_TILE) shape against the whole-round oracle
+``favas_fused_ref`` (structural check; interpret-mode *timing* is
+meaningless — TPU is the target). The artifact keeps its key names
+(``favas_round_fused_*`` is the step).
 """
 from __future__ import annotations
 
@@ -25,9 +28,29 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import timed, save_artifact
+from repro.core import round_engine
 from repro.kernels import ref
 from repro.kernels.favas_agg import CLIENT_TILE, TILE
-from repro.kernels.ops import favas_fused_flat, luq_quantize
+from repro.kernels.ops import luq_quantize
+
+
+def _round_step(server, clients, use_kernel=False):
+    """The engine's round step on one bucket of raw (D,) / (n, D) buffers,
+    jitted; the selection is the rows ``mask`` selects (its indices are
+    taken on the host, so ``s`` is ``mask``'s sum)."""
+    n = clients.shape[0]
+    spec = round_engine.make_flat_spec({"w": server}, n_clients=n)
+
+    def step(w, c, i, a, m, sel_idx):
+        (srv,), (cli,), (ini,) = round_engine.aggregate_and_reset(
+            spec, (w,), (c,), (i,), a, m, sel_idx, sel_idx.shape[0],
+            n_logical=n, use_kernel=use_kernel)
+        return srv, cli, ini
+    return jax.jit(step)
+
+
+def _sel(mask):
+    return jnp.asarray(np.flatnonzero(np.asarray(mask)), jnp.int32)
 
 
 def _round_unfused(server, clients, inits, alpha, mask, s):
@@ -53,23 +76,24 @@ def run(quick=True):
     inits = jax.random.normal(ks[2], (n, D))
     alpha = jax.random.uniform(ks[3], (n,), minval=1.0, maxval=8.0)
     mask = (jax.random.uniform(ks[4], (n,)) > 0.5).astype(jnp.float32)
-    s = 4.0
+    sel = _sel(mask)
+    s = float(sel.shape[0])
 
-    # full round: aggregation + reset — fused (engine path) vs seed multi-pass
-    fused = jax.jit(lambda *a: ref.favas_fused_ref(*a, s))
+    # full round: aggregation + reset — the engine's step vs seed multi-pass
+    fused = _round_step(server, clients)
     unfused = jax.jit(lambda *a: _round_unfused(*a, s))
-    t_fused = timed(fused, server, clients, inits, alpha, mask, reps=10)
+    t_fused = timed(fused, server, clients, inits, alpha, mask, sel, reps=10)
     t_unfused = timed(unfused, server, clients, inits, alpha, mask, reps=10)
 
-    # aggregation only (the seed's single-output kernel scope)
-    agg_ref = jax.jit(lambda *a: ref.favas_agg_ref(*a, s))
+    # aggregation only (the kernel's scope)
+    agg_ref = jax.jit(lambda *a: ref.favas_stream_ref(*a, s))
     t_agg = timed(agg_ref, server, clients, inits, alpha, mask, reps=10)
 
     x = jax.random.normal(key, (D,))
     luq_ref_fn = jax.jit(lambda x, k: luq_quantize(x, 4, k, use_kernel=False))
     t_luq = timed(luq_ref_fn, x, key, reps=10)
 
-    # structural validation of the multi-output Pallas kernel (compiled on
+    # structural validation of the step on the Pallas kernel (compiled on
     # a TPU, interpret mode elsewhere): one resident shape, one tiled shape
     # (client blocks + row padding)
     kernel_ok = True
@@ -80,14 +104,15 @@ def run(quick=True):
         iv = jax.random.normal(kv[2], (nv, Dv))
         av = jax.random.uniform(kv[3], (nv,), minval=1.0, maxval=8.0)
         mv = (jax.random.uniform(kv[4], (nv,)) > 0.5).astype(jnp.float32)
-        got = favas_fused_flat(sv, cv, iv, av, mv, 2.0, use_kernel=True)
-        want = ref.favas_fused_ref(sv, cv, iv, av, mv, 2.0)
+        sl = _sel(mv)
+        got = _round_step(sv, cv, use_kernel=True)(sv, cv, iv, av, mv, sl)
+        want = ref.favas_fused_ref(sv, cv, iv, av, mv, float(sl.shape[0]))
         kernel_ok = kernel_ok and all(
             np.allclose(np.asarray(g), np.asarray(w), rtol=1e-6, atol=1e-6)
             for g, w in zip(got, want))
 
     # client-count sweep at constant total resident bytes: the engine's
-    # fused round (what the tiled kernel streams on TPU) vs the seed's
+    # round step (what the tiled kernel streams on TPU) vs the seed's
     # multi-pass arithmetic, n from demo scale to production federations
     # 2^23 quick / 2^24 full keeps D >= TILE at n=4096, so every sweep point
     # really does hold the same element count (constant working set)
@@ -101,9 +126,9 @@ def run(quick=True):
         iw = jax.random.normal(kw[2], (ns, Ds))
         aw = jax.random.uniform(kw[3], (ns,), minval=1.0, maxval=8.0)
         mw = (jax.random.uniform(kw[4], (ns,)) > 0.5).astype(jnp.float32)
-        ssel = float(mw.sum())
-        t_f = timed(jax.jit(lambda *a: ref.favas_fused_ref(*a, ssel)),
-                    sw, cw, iw, aw, mw, reps=5)
+        sl = _sel(mw)
+        ssel = float(sl.shape[0])
+        t_f = timed(_round_step(sw, cw), sw, cw, iw, aw, mw, sl, reps=5)
         t_u = timed(jax.jit(lambda *a: _round_unfused(*a, ssel)),
                     sw, cw, iw, aw, mw, reps=5)
         bytes_n = (4 * ns + 2) * Ds * 4
@@ -135,8 +160,9 @@ def run(quick=True):
         "n_sweep": n_sweep,
         "sharded_round": sharded,
         "fused_kernel_interpret_matches_ref": bool(kernel_ok),
-        "note": "fused = the engine's real round path (agg + reset, one pass);"
-                " unfused = the seed's multi-pass arithmetic. n_sweep holds"
+        "note": "fused = the engine's round step (aggregation, then the"
+                " selected rows' reset); unfused = the seed's multi-pass"
+                " arithmetic. n_sweep holds"
                 " n*D (the resident client working set) constant while n"
                 " scales to production federation sizes (the tiled"
                 " client-axis regime). Pallas"
@@ -174,9 +200,9 @@ def _run_sharded_subprocess(timeout: int = 900) -> dict:
 
 
 def _sharded_child():
-    """Child body: time the fused FAVAS round on flat buffers sharded over
+    """Child body: time the FAVAS round step on flat buffers sharded over
     an 8-way ("model",) mesh (shard_map/pjit dispatch via
-    ``round_engine.fused_bucket_update``) vs the replicated single-device
+    ``round_engine.aggregate_and_reset``) vs the replicated single-device
     engine at the same shapes, and audit the sharded HLO for all-gathers.
     CPU "devices" here are host threads, so the us columns measure overhead
     structure, not TPU speedup — the all_gather_bytes column is the point:
@@ -203,17 +229,18 @@ def _sharded_child():
                         constant_values=1.0)
         mask = jnp.pad((jax.random.uniform(kw[4], (ns,)) > 0.5)
                        .astype(jnp.float32), (0, rows - ns))
-        s = float(mask.sum())
+        sel = _sel(mask)
         sh = round_engine.engine_sharding(spec_s, mesh)
         srv_s = jax.device_put(srv, sh.server[0])
         cli_s = jax.device_put(cli, sh.clients[0])
         ini_s = jax.device_put(ini, sh.inits[0])
 
-        step_sh = jax.jit(lambda w, c, i, a, m: round_engine.fused_bucket_update(
-            spec_s, 0, w, c, i, a, m, s, n_logical=ns, mesh=mesh,
-            use_kernel=False))
-        step_rep = jax.jit(lambda w, c, i, a, m: round_engine.fused_bucket_update(
-            spec_r, 0, w, c, i, a, m, s, n_logical=ns, use_kernel=False))
+        def step(spec, mesh):
+            return jax.jit(
+                lambda w, c, i, a, m: round_engine.aggregate_and_reset(
+                    spec, (w,), (c,), (i,), a, m, sel, sel.shape[0],
+                    n_logical=ns, mesh=mesh, use_kernel=False))
+        step_sh, step_rep = step(spec_s, mesh), step(spec_r, None)
         t_sh = timed(step_sh, srv_s, cli_s, ini_s, alpha, mask, reps=5)
         t_rep = timed(step_rep, srv, cli, ini, alpha, mask, reps=5)
         hlo = step_sh.lower(srv_s, cli_s, ini_s, alpha, mask).compile().as_text()

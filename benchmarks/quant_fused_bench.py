@@ -1,5 +1,6 @@
 """Quantized-transport round benchmark: codes-in fused rounds vs the
-unfused decode -> round -> requant composition (docs/architecture.md §10).
+unfused decode -> round -> requant composition (docs/architecture.md §10);
+a CPU design aid (device numbers come from ``bench/`` on the chip).
 
 Both arms run the same logical FAVAS[QNN] loop: the transmitted progress
 lives as bit-packed LUQ codes + per-row scales between rounds, each round
@@ -8,13 +9,14 @@ the next round. The difference is the TRANSPORT:
 
 * **unfused** (the pre-PR-7 composition) — three separate jitted
   dispatches per round: ``luq_decode_rows`` materializes the dense (n, D)
-  f32 progress in HBM, ``favas_fused_flat`` consumes it, and
+  f32 progress in HBM, the engine's round step
+  (``round_engine.aggregate_and_reset``) consumes it, and
   ``luq_encode_rows`` re-encodes. The dense progress buffer crosses HBM
   twice per round (decode write + round read) on top of the dispatch
   overhead.
 * **fused** — ONE jitted ``lax.scan`` over the whole chunk whose body
-  feeds the codes straight into ``favas_fused_flat(progress_codes=...)``
-  (dequantized inside the round — per VMEM tile on the kernel path) and
+  feeds the codes straight into the round step (``progress_codes=``;
+  dequantized inside the aggregation — per VMEM tile on the kernel path) and
   re-encodes via ``kernels.ops.cold_requant_rows``. No standalone decode
   dispatch, no host round-trips inside the chunk.
 
@@ -41,8 +43,9 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import save_artifact
+from repro.core import round_engine
 from repro.core.paging import luq_decode_rows, luq_encode_rows
-from repro.kernels.ops import cold_requant_rows, favas_fused_flat
+from repro.kernels.ops import cold_requant_rows
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -66,14 +69,30 @@ def _setup(n: int, seed: int = 0):
     return server, clients, inits, alpha, mask, float(s), enc0, key
 
 
+def _step(n, alpha, mask, s, **progress):
+    """The engine's round step (oracle path) on one (n, D) bucket; the
+    selection is the rows ``mask`` selects."""
+    spec = round_engine.make_flat_spec({"w": jnp.zeros((D,), jnp.float32)},
+                                       n_clients=n)
+    sel_idx = jnp.flatnonzero(mask, size=int(s))
+
+    def step(srv, cli, ini, prog=None, enc=None):
+        (srv,), (cli,), (ini,) = round_engine.aggregate_and_reset(
+            spec, (srv,), (cli,), (ini,), alpha, mask, sel_idx, s,
+            progress=None if prog is None else (prog,),
+            progress_codes=None if enc is None else (enc,),
+            progress_bits=BITS, n_logical=n, use_kernel=False)
+        return srv, cli, ini
+    return step
+
+
 def _run_unfused(n: int, reps: int) -> dict:
     """Host loop, three dispatches per round: decode -> dense round ->
     requant. The (n, D) f32 progress exists in HBM between dispatches."""
     server, clients, inits, alpha, mask, s, enc0, key = _setup(n)
 
     decode = jax.jit(lambda e: luq_decode_rows(e, BITS, jnp.float32))
-    rnd = jax.jit(lambda srv, cli, ini, prog: favas_fused_flat(
-        srv, cli, ini, alpha, mask, s, progress=prog, use_kernel=False))
+    rnd = jax.jit(_step(n, alpha, mask, s))
     requant = jax.jit(lambda cli, ini, k: luq_encode_rows(
         cli.astype(jnp.float32) - ini.astype(jnp.float32), BITS, k))
 
@@ -100,12 +119,11 @@ def _run_unfused(n: int, reps: int) -> dict:
 def _run_fused(n: int, reps: int) -> dict:
     """One jitted scan per chunk; the body consumes codes directly."""
     server, clients, inits, alpha, mask, s, enc0, key = _setup(n)
+    step = _step(n, alpha, mask, s)
 
     def body(carry, r):
         srv, cli, ini, enc = carry
-        srv, cli, ini = favas_fused_flat(
-            srv, cli, ini, alpha, mask, s, progress_codes=enc,
-            progress_bits=BITS, use_kernel=False)
+        srv, cli, ini = step(srv, cli, ini, enc=enc)
         enc = cold_requant_rows(
             cli.astype(jnp.float32) - ini.astype(jnp.float32), BITS,
             jax.random.fold_in(key, r), use_kernel=False)

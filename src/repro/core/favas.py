@@ -19,8 +19,10 @@ One round (server timestep t -> t+1):
   4. w_{t+1} = (w_t + sum_{i in S_t} w_unbiased^i) / (s+1)     [line 10]
   5. selected clients reset: w^i = w_init^i = w_{t+1}, q^i = 0
 
-Steps 3–5 run as ONE fused pass over flat parameter buffers through
-``core.round_engine`` (Pallas kernel on TPU, jnp oracle on CPU); this module
+Steps 4–5 run as the engine's round step over flat parameter buffers
+(``core.round_engine.aggregate_and_reset``: one aggregation pass per
+buffer, Pallas kernel on TPU, jnp oracle on CPU; then the selected rows'
+reset); this module
 keeps the pytree API by flattening/unflattening at the call boundary. The
 seed's per-leaf ``tree_map`` implementation survives only as
 ``favas_round_reference`` — the numerical oracle the engine is regression-
@@ -142,7 +144,7 @@ def favas_round(state: FavasState, batch, *, cfg: FavasConfig, loss_fn: Callable
     ``use_kernel``: None -> Pallas kernel on TPU, jnp oracle elsewhere;
     True/False force the choice (True runs interpret mode off-TPU).
     ``mesh``: bucket the flat buffers by (dtype, sharding group) and keep
-    model-sharded leaves sharded through the fused round (no full-buffer
+    model-sharded leaves sharded through the round (no full-buffer
     gather; see core/round_engine.py and docs/architecture.md §6)."""
     return _on_engine(round_engine.engine_round, state, batch, cfg=cfg,
                       mesh=mesh, loss_fn=loss_fn, lambdas=lambdas,

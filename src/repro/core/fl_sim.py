@@ -20,9 +20,10 @@ distributed production trainer for the assigned architectures lives in
 The FAVAS and QuAFL branches run as **supersteps** (docs/architecture.md
 §7): every eval-to-eval window of server rounds is ONE jitted, donated
 ``jax.lax.scan`` over the flat-buffer engine — client selection
-(``sampler.sample_selection``), the deterministic credit/step-time clock
-(``sampler.credit_steps``, on exact integer ticks), eq. 3 alphas, and the
-q bookkeeping all live on-device inside the scan. The host only syncs at
+(``sampler.sample_selection_indices``), the deterministic credit/step-time
+clock (``sampler.credit_steps``, on exact integer ticks), eq. 3 alphas, the
+engine's round step and the q bookkeeping all live on-device inside the
+scan. The host only syncs at
 eval boundaries. Batches come from one of two data planes
 (``SimConfig.data_plane``, docs/architecture.md §8): ``"host"`` generates
 them in numpy on a background-thread ``BatchPrefetcher`` while the current
@@ -142,8 +143,8 @@ def run_simulation(cfg: SimConfig, data, *, d_hidden: int = 128,
 
     ``mesh``: optional device mesh with a "model" axis — the FAVAS branch
     then builds a sharding-aware FlatSpec (hidden-dim leaves bucketed into
-    model-sharded flat buffers, see sharding/rules.py) and runs the fused
-    poll through ``round_engine.fused_bucket_update`` without gathering the
+    model-sharded flat buffers, see sharding/rules.py) and runs the poll
+    through ``round_engine.aggregate_and_reset`` without gathering the
     buffers. CPU default (mesh=None) is unchanged."""
     xtr, ytr, xte, yte, parts = data
     n_classes = int(ytr.max()) + 1
@@ -180,8 +181,9 @@ def run_simulation(cfg: SimConfig, data, *, d_hidden: int = 128,
         R = int(np.ceil(round_dur / step_time.min()))
         sgd = _local_sgd_batched(loss_fn, cfg.eta, R)
         # Flat-buffer engine state, held across rounds for BOTH methods: the
-        # FAVAS poll (eq. 3 + line 10 + reset) runs as ONE fused pass per
-        # dtype bucket and the QuAFL convex-combination poll as one flat
+        # FAVAS poll (eq. 3 + line 10 + reset) is the engine's round step
+        # (one aggregation pass per dtype bucket, then the selected rows'
+        # reset) and the QuAFL convex-combination poll one flat
         # elementwise pass — no per-leaf tree_map sweeps on the hot path.
         # The spec is client-aware (row padding beyond one client tile) and,
         # with a mesh, sharding-aware (model-sharded hidden-dim buckets).
@@ -203,17 +205,18 @@ def run_simulation(cfg: SimConfig, data, *, d_hidden: int = 128,
         def one_round(carry, batch):
             """Scan body: ONE server round, everything on-device — the
             credit clock, masked local SGD, Gumbel top-s selection, eq. 3
-            alphas, the fused poll, and the q reset."""
+            alphas, the poll, and the q reset."""
             srv_f, cli_f, ini_f, q, credit, rkey = carry
             xs_t, ys_t = batch
             # SELECT FIRST (docs/architecture.md §9): the round's selection
             # is drawn before any client buffer is touched, mirroring the
-            # paged engine's select -> gather -> fused -> scatter order.
-            # The split positions are unchanged, so the streams (and every
-            # regression baseline) are bit-identical to the old
-            # train-then-select body.
+            # paged engine's select -> gather -> aggregate -> scatter
+            # order. The split positions are unchanged, so the streams (and
+            # every regression baseline) are bit-identical to the old
+            # train-then-select body; the mask is the indicator of sel_idx.
             rkey, k_sel, k_q = jax.random.split(rkey, 3)
-            mj = sampler.sample_selection(k_sel, n, cfg.s_selected)
+            sel_idx, mj = sampler.sample_selection_indices(
+                k_sel, n, cfg.s_selected)
             do, credit = sampler.credit_steps(credit, step_ticks_j, q,
                                               cfg.K, round_ticks)
             clients_t = round_engine.unflatten_stacked(spec, cli_f)
@@ -243,15 +246,9 @@ def run_simulation(cfg: SimConfig, data, *, d_hidden: int = 128,
                         stacked=True)
                 alpha_p = round_engine.pad_client_vec(spec, alpha, 1.0)
                 mj_p = round_engine.pad_client_vec(spec, mj, 0.0)
-                out = [round_engine.fused_bucket_update(
-                           spec, b, w, c, i, alpha_p, mj_p,
-                           float(cfg.s_selected), progress_b=p,
-                           n_logical=n, mesh=mesh)
-                       for b, (w, c, i, p) in enumerate(
-                           zip(srv_f, cli_f, ini_f, prog_f))]
-                srv_f = tuple(o[0] for o in out)
-                cli_f = tuple(o[1] for o in out)
-                ini_f = tuple(o[2] for o in out)
+                srv_f, cli_f, ini_f = round_engine.aggregate_and_reset(
+                    spec, srv_f, cli_f, ini_f, alpha_p, mj_p, sel_idx,
+                    cfg.s_selected, progress=prog_f, n_logical=n, mesh=mesh)
             else:  # QuAFL (Zakerinia et al. 2022): convex combos, no
                 #    reweight — same flat buffers, one elementwise pass per
                 #    bucket (padded rows have zero mask and stay zero)

@@ -147,7 +147,7 @@ class PassthroughCodec:
     """Identity cold codec: rows are stored verbatim (client AND init).
 
     No compression — this codec exists so the paged control flow (select ->
-    gather -> fused round -> scatter-back) can be proven BIT-EXACT against
+    gather -> round -> scatter-back) can be proven BIT-EXACT against
     the dense engine, independently of any quantization effect."""
 
     def encode_pair(self, cli, init, key, *, shards: int = 1,
@@ -192,7 +192,7 @@ class LuqCodec:
                     use_kernel=None) -> Dict:
         # route through kernels.ops so the requant dispatch point is shared
         # with the rest of the kernel surface: ``use_kernel`` picks the
-        # code-emitting Pallas kernel exactly like the fused-round knob
+        # code-emitting Pallas kernel exactly like the round's knob
         # (None = TPU auto, True = kernel / interpret off-TPU, False = jnp
         # oracle — the two are bit-identical under shared uniforms)
         from repro.kernels.ops import cold_dequant_rows, cold_requant_rows

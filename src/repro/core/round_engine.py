@@ -10,11 +10,13 @@ engine instead:
   ``(Dp,)`` server vector and ``(n, Dp)`` clients / inits matrices per
   dtype bucket, pre-padded to the kernel lane tile so the Pallas path never
   re-pads — and holds them across rounds;
-* runs the whole aggregation + reset as ONE streamed pass per tile through
-  the multi-output Pallas kernel ``kernels.favas_agg.favas_fused_pallas``
-  (TPU; interpret for validation) or its jnp oracle
-  ``kernels.ref.favas_fused_ref`` (CPU default — XLA fuses the flat-buffer
-  expression into a single loop, which is already the oracle's point);
+* aggregates each bucket in ONE streamed pass through the Pallas kernel
+  ``kernels.favas_agg.favas_stream_pallas`` (TPU; interpret for
+  validation) or its jnp oracle ``kernels.ref.favas_stream_ref`` (CPU
+  default — XLA fuses the flat-buffer expression into a single loop), then
+  writes the new server row into the selected clients' rows of the donated
+  stacks (:func:`aggregate_and_reset`, the one round step every caller
+  shares);
 * unflattens only at the boundaries that need model structure: the vmapped
   local-SGD step (which needs the pytree for the model's loss), evaluation,
   and checkpoint export.
@@ -24,7 +26,7 @@ engine instead:
 ``launch.train`` uses ``RoundEngine`` directly so the buffers genuinely
 persist across rounds and the jitted round donates them.
 
-Beyond the fused single round, ``engine_multi_round`` /
+Beyond the single round, ``engine_multi_round`` /
 ``RoundEngine.run`` scan a whole CHUNK of rounds on-device — one jitted,
 buffer-donating dispatch and one stacked metrics fetch per chunk instead
 of per round ("supersteps", docs/architecture.md §7) — which removes the
@@ -44,7 +46,7 @@ from repro.core import sampler, reweight
 from repro.core.paging import PassthroughCodec, make_codec
 from repro.core.quant import quantize_tree
 from repro.kernels.favas_agg import CLIENT_TILE, TILE
-from repro.kernels.ops import favas_fused_flat, favas_stream_flat
+from repro.kernels.ops import favas_stream_flat
 from repro.utils.profiling import scoped
 from repro.utils.tree import tree_map
 
@@ -61,7 +63,7 @@ class FlatSpec:
     Leaves keep their original dtype; mixed-precision trees get one buffer
     per dtype so no storage precision is lost. Buffer length is padded up to
     a multiple of the kernel lane tile; the padded tail is zero-initialized
-    and provably stays zero under the fused round update (the masked padded
+    and provably stays zero under the round update (the masked padded
     "server" tail aggregates only zeros).
 
     When built with ``n_clients``, the spec is client-aware: stacked buffers
@@ -79,9 +81,9 @@ class FlatSpec:
     shards of that shard's slice of every leaf, each per-shard segment
     independently padded to the lane tile. Partitioning the flat axis into S
     equal contiguous blocks (``PartitionSpec("model")``) therefore hands
-    each device exactly its own leaf shards: flatten, the fused round, and
+    each device exactly its own leaf shards: flatten, the round, and
     unflatten all stay communication-free on the model axis (no full-buffer
-    all-gather; see ``fused_bucket_update``). Invariant:
+    all-gather; see ``stream_bucket_update``). Invariant:
     ``bucket_padded[b] == bucket_shards[b] * bucket_shard_padded[b]``.
     """
     treedef: Any
@@ -424,7 +426,7 @@ def stack_server_rows(spec: FlatSpec, server_bufs: Sequence, n: int) -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# Mesh-aware execution: shardings, constraints, and the per-bucket fused call
+# Mesh-aware execution: shardings, constraints, and the per-bucket aggregation
 # ---------------------------------------------------------------------------
 
 def bucket_partition_specs(spec: FlatSpec, *, stacked: bool) -> tuple:
@@ -508,16 +510,17 @@ def _constrain_cold(spec: FlatSpec, mesh, cold) -> tuple:
 
 
 @scoped("favas_aggregate")
-def _bucket_dispatch(flat_fn, spec: FlatSpec, b: int, server_b, trained_b,
-                     inits_b, alpha_p, mask_p, s: float, *, progress_b,
-                     progress_codes_b, progress_bits: int, n_logical, mesh,
-                     use_kernel, stacked_outputs: bool):
-    """The mesh-aware dispatch shared by :func:`fused_bucket_update` and
-    :func:`stream_bucket_update` (docs/architecture.md §6). ``flat_fn`` is
-    ``favas_fused_flat`` or ``favas_stream_flat``; ``stacked_outputs`` says
-    whether it also returns the (n, Dp) client/init stacks.
+def stream_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
+                         alpha_p, mask_p, s: float, *, progress_b=None,
+                         progress_codes_b=None, progress_bits: int = 0,
+                         n_logical: Optional[int] = None, mesh=None,
+                         use_kernel: Optional[bool] = None):
+    """One bucket's aggregation (Alg. 1 line 10 + eq. 3), mesh-aware
+    (docs/architecture.md §6, §13). Returns the new server vector with the
+    server's sharding; :func:`aggregate_and_reset` then writes it into the
+    selected rows of the donated client/init buffers.
 
-    * no mesh -> a plain ``flat_fn`` call (kernel or oracle);
+    * no mesh -> a plain ``favas_stream_flat`` call (kernel or oracle);
     * kernel on a mesh -> ``shard_map``: each device runs the Pallas kernel
       on its own (n, Dp_b/S) lane slice of a model-sharded bucket, or on
       the whole bucket when it is replicated (the chip's compiler cannot
@@ -525,8 +528,8 @@ def _bucket_dispatch(flat_fn, spec: FlatSpec, b: int, server_b, trained_b,
       mesh). Slices are lane-tile aligned by construction (per-shard
       padding), the client reduction is shard-local, and the body contains
       no collectives — the round cannot all-gather the buffer;
-    * oracle on a mesh -> the jnp expression; a sharded bucket gets
-      explicit output ``PartitionSpec`` constraints so GSPMD partitions
+    * oracle on a mesh -> the jnp expression; a sharded bucket gets an
+      explicit output ``PartitionSpec`` constraint so GSPMD partitions
       the elementwise lanes and the (unsharded) client reduction locally.
 
     ``progress_codes_b`` (mutually exclusive with ``progress_b``): the
@@ -542,17 +545,16 @@ def _bucket_dispatch(flat_fn, spec: FlatSpec, b: int, server_b, trained_b,
     common = dict(progress_bits=progress_bits, client_tile=spec.client_tile,
                   n_logical=n_logical)
     if mesh is None:
-        return flat_fn(server_b, trained_b, inits_b, alpha_p, mask_p,
-                       float(s), progress=progress_b,
-                       progress_codes=progress_codes_b,
-                       progress_shards=max(1, spec.shards(b)),
-                       use_kernel=use_kernel, **common)
+        return favas_stream_flat(server_b, trained_b, inits_b, alpha_p,
+                                 mask_p, float(s), progress=progress_b,
+                                 progress_codes=progress_codes_b,
+                                 progress_shards=max(1, spec.shards(b)),
+                                 use_kernel=use_kernel, **common)
     kernel_active = (use_kernel if use_kernel is not None
                      else jax.default_backend() == "tpu")
     from jax.sharding import NamedSharding, PartitionSpec as P
     ax = spec.mesh_axis if spec.shards(b) > 1 else None
     lane, row, vec = P(ax), P(None, ax), P(None)
-    out_specs = (lane, row, row) if stacked_outputs else lane
     if kernel_active:
         def body(*ops):
             pr = pc = None
@@ -565,9 +567,10 @@ def _bucket_dispatch(flat_fn, spec: FlatSpec, b: int, server_b, trained_b,
                 srv, cli, ini, al, mk = ops
             # per-device view: the local codes slice is one shard segment
             # with its own scale column -> progress_shards=1
-            return flat_fn(srv, cli, ini, al, mk, float(s), progress=pr,
-                           progress_codes=pc, progress_shards=1,
-                           use_kernel=True, **common)
+            return favas_stream_flat(srv, cli, ini, al, mk, float(s),
+                                     progress=pr, progress_codes=pc,
+                                     progress_shards=1, use_kernel=True,
+                                     **common)
 
         operands = [server_b, trained_b, inits_b]
         in_specs = [lane, row, row]
@@ -582,50 +585,15 @@ def _bucket_dispatch(flat_fn, spec: FlatSpec, b: int, server_b, trained_b,
         operands += [alpha_p, mask_p]
         in_specs += [vec, vec]
         return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                             out_specs=out_specs, check_vma=False)(*operands)
-    out = flat_fn(server_b, trained_b, inits_b, alpha_p, mask_p, float(s),
-                  progress=progress_b, progress_codes=progress_codes_b,
-                  progress_shards=spec.shards(b), use_kernel=False, **common)
+                             out_specs=lane, check_vma=False)(*operands)
+    out = favas_stream_flat(server_b, trained_b, inits_b, alpha_p, mask_p,
+                            float(s), progress=progress_b,
+                            progress_codes=progress_codes_b,
+                            progress_shards=spec.shards(b), use_kernel=False,
+                            **common)
     if ax is None:
         return out
-    return jax.tree_util.tree_map(
-        lambda o, p: jax.lax.with_sharding_constraint(o, NamedSharding(mesh, p)),
-        out, out_specs, is_leaf=lambda x: isinstance(x, P))
-
-
-def fused_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
-                        alpha_p, mask_p, s: float, *, progress_b=None,
-                        progress_codes_b=None, progress_bits: int = 0,
-                        n_logical: Optional[int] = None, mesh=None,
-                        use_kernel: Optional[bool] = None):
-    """One bucket's fused aggregation + selected-client reset, mesh-aware
-    (dispatch: :func:`_bucket_dispatch`). Returns (server_new, clients_new,
-    inits_new) with the inputs' shardings."""
-    return _bucket_dispatch(
-        favas_fused_flat, spec, b, server_b, trained_b, inits_b, alpha_p,
-        mask_p, s, progress_b=progress_b, progress_codes_b=progress_codes_b,
-        progress_bits=progress_bits, n_logical=n_logical, mesh=mesh,
-        use_kernel=use_kernel, stacked_outputs=True)
-
-
-def stream_bucket_update(spec: FlatSpec, b: int, server_b, trained_b, inits_b,
-                         alpha_p, mask_p, s: float, *, progress_b=None,
-                         progress_codes_b=None, progress_bits: int = 0,
-                         n_logical: Optional[int] = None, mesh=None,
-                         use_kernel: Optional[bool] = None):
-    """One bucket's STREAMED aggregation (docs/architecture.md §13):
-    the :func:`fused_bucket_update` dispatch contract, returning ONLY the
-    new server vector. The caller writes this row into the selected rows
-    of the donated client/init buffers (:func:`_streamed_reset`) — on
-    large stacks by a churn-bounded scatter that never rewrites the
-    unselected rows, so per-bucket round traffic drops from ~2R+2W to 1R
-    (+ O(s * Dp) scatter writes) per resident byte. Bit-identical server
-    to ``fused_bucket_update`` per dispatch path."""
-    return _bucket_dispatch(
-        favas_stream_flat, spec, b, server_b, trained_b, inits_b, alpha_p,
-        mask_p, s, progress_b=progress_b, progress_codes_b=progress_codes_b,
-        progress_bits=progress_bits, n_logical=n_logical, mesh=mesh,
-        use_kernel=use_kernel, stacked_outputs=False)
+    return jax.lax.with_sharding_constraint(out, NamedSharding(mesh, lane))
 
 
 def reset_path(rows: int, s: int, dtype) -> str:
@@ -649,10 +617,10 @@ def _streamed_reset(spec: FlatSpec, mesh, bufs, sel_idx, rows):
     scatter, which XLA performs in place so unselected rows are never
     rewritten (the write-traffic audit in launch/roofline.py pins this);
     small stacks take one masked pass (:func:`reset_path`). Both are
-    bit-exact vs the fused reset: the mask is exactly the indicator of
-    ``sel_idx`` and the fused ``m*s_new + (1-m)*x`` blend is ``x`` (exact
-    f32 round-trip) off-selection and ``s_new.astype(dtype)`` — the
-    written row — on it."""
+    bit-exact vs the blended reset of ``kernels.ref.favas_fused_ref``: the
+    mask is exactly the indicator of ``sel_idx`` and the blend
+    ``m*s_new + (1-m)*x`` is ``x`` (exact f32 round-trip) off-selection
+    and ``s_new.astype(dtype)`` — the written row — on it."""
     out = []
     for buf, row in zip(bufs, rows):
         row = row.astype(buf.dtype)
@@ -662,6 +630,38 @@ def _streamed_reset(spec: FlatSpec, mesh, bufs, sel_idx, rows):
         else:
             out.append(buf.at[sel_idx].set(row))
     return _constrain_buckets(spec, mesh, out, stacked=True)
+
+
+def aggregate_and_reset(spec: FlatSpec, server, trained, inits, alpha_p,
+                        mask_p, sel_idx, s: float, *, progress=None,
+                        progress_codes=None, progress_bits: int = 0,
+                        n_logical: Optional[int] = None, mesh=None,
+                        use_kernel: Optional[bool] = None):
+    """Algorithm 1 lines 10-12 on flat buffers, the one round step that
+    the dense and paged engine rounds, ``fl_sim`` and the async server
+    share: every bucket's aggregation (:func:`stream_bucket_update`), then
+    the new server rows written into the ``sel_idx`` rows of the
+    (donated) trained stacks, then of the init stacks
+    (:func:`_streamed_reset`).
+
+    ``server``, ``trained`` and ``inits`` are per-bucket tuples;
+    ``alpha_p``/``mask_p`` the eq. 3 coefficients and the selection mask
+    padded to the stacks' rows (unit alpha / zero mask on padded rows);
+    ``mask_p`` must be the 0/1 indicator of ``sel_idx``. ``progress`` /
+    ``progress_codes``: per-bucket transmitted progress (FAVAS[QNN]), None
+    for ``trained - inits``. Returns ``(server_new, clients_new,
+    inits_new)`` as tuples."""
+    nb = spec.n_buckets
+    progress = progress or (None,) * nb
+    progress_codes = progress_codes or (None,) * nb
+    server_new = [stream_bucket_update(
+        spec, b, server[b], trained[b], inits[b], alpha_p, mask_p, float(s),
+        progress_b=progress[b], progress_codes_b=progress_codes[b],
+        progress_bits=progress_bits, n_logical=n_logical, mesh=mesh,
+        use_kernel=use_kernel) for b in range(nb)]
+    clients_new = _streamed_reset(spec, mesh, trained, sel_idx, server_new)
+    inits_new = _streamed_reset(spec, mesh, inits, sel_idx, server_new)
+    return tuple(server_new), clients_new, inits_new
 
 
 def slab_shardings(spec: FlatSpec, mesh):
@@ -876,12 +876,13 @@ def engine_round(spec: FlatSpec, state: EngineState, batch=None, *, cfg,
                  det_alpha: Optional[jnp.ndarray] = None,
                  use_kernel: Optional[bool] = None, mesh=None,
                  quant_fused: bool = False, corpus=None, batch_key=None,
-                 schedule: str = "streamed", slab=None, plan=None):
+                 slab=None, plan=None):
     """One FAVAS server round on flat buffers. Pure; jit/pjit this.
 
     The hot path is: unflatten clients -> vmapped local SGD -> flatten ->
-    ONE fused aggregation+reset pass per bucket. No per-leaf tree_map
-    touches the aggregation.
+    ONE aggregation pass per bucket -> the selected rows reset
+    (:func:`aggregate_and_reset`). No per-leaf tree_map touches the
+    aggregation.
 
     Args:
       spec: the :func:`make_flat_spec` layout the buffers follow.
@@ -899,31 +900,22 @@ def engine_round(spec: FlatSpec, state: EngineState, batch=None, *, cfg,
         True/False force the choice (True runs interpret mode off-TPU).
       quant_fused: FAVAS[QNN] transport format. False (default, the seed
         semantics) quantizes the transmitted progress in tree space with
-        per-leaf scales and hands the fused pass a dense dequantized
+        per-leaf scales and hands the aggregation a dense dequantized
         (n, Dp) buffer. True encodes the progress per BUCKET as bit-packed
         LUQ codes + per-(row, shard) scales (``kernels.ops.
-        cold_requant_rows``) and hands the fused pass the CODES — the
+        cold_requant_rows``) and hands the aggregation the CODES — the
         kernel dequantizes per VMEM tile, so no full-precision (n, Dp)
         progress buffer ever materializes (different per-row-vs-per-leaf
         scale granularity and key stream, so an opt-in knob, not a drop-in
         replacement for the seed path).
       mesh: optional device mesh matching a sharding-aware ``spec``. Sharded
-        buckets then run their fused pass via :func:`fused_bucket_update`
-        (shard_map on the kernel path, pjit constraints on the oracle path)
-        so the round never gathers a full buffer onto one device.
+        buckets then aggregate via :func:`stream_bucket_update` (shard_map
+        on the kernel path, pjit constraints on the oracle path) so the
+        round never gathers a full buffer onto one device.
       corpus / batch_key: device data plane — instead of ``batch``, a
         resident :class:`repro.data.device_corpus.DeviceCorpus` plus the
         round's batch key; the round samples its own minibatches (and, on a
         paged spec, gathers corpus rows for the hot working set only).
-      schedule: "streamed" (default, docs/architecture.md §13) aggregates
-        with the single-sweep :func:`stream_bucket_update` and resets the
-        s selected rows of the donated buffers (:func:`_streamed_reset`:
-        a churn-bounded scatter, ~1R+1W per resident byte with no
-        pass-through rewrites, or one masked pass on small stacks);
-        "two_sweep" keeps the historical fused aggregation+reset kernel
-        (~2R+2W). The two schedules are BIT-EXACT — the mask is exactly
-        the indicator of the Gumbel top-s index set — so the knob only
-        changes traffic, never values.
       slab / plan: host-tier cold paging (paged specs with
         ``cold_placement="host"`` only): the chunk's churned cold pages as
         a device slab plus this round's slab positions — see
@@ -932,21 +924,19 @@ def engine_round(spec: FlatSpec, state: EngineState, batch=None, *, cfg,
 
     On a ``residency="paged"`` spec the round runs the hot/cold body
     (:func:`_paged_round`): select -> promote/evict the hot working set ->
-    gather+dequant -> fused round over the s_max hot rows -> requant+
+    gather+dequant -> the round over the s_max hot rows -> requant+
     scatter-back. With the passthrough codec at ``s_max == n`` it is
     bit-exact with this dense body (tests/test_paged_engine.py).
 
     Returns ``(new_state, metrics)`` where metrics holds the live-step-
     weighted ``loss``, ``mean_steps``, ``selected`` and ``stale_rounds``."""
-    if schedule not in ("streamed", "two_sweep"):
-        raise ValueError(f"unknown schedule {schedule!r}")
     if spec.paged:
         return _paged_round(spec, state, batch, cfg=cfg, loss_fn=loss_fn,
                             lambdas=lambdas, det_alpha=det_alpha,
                             use_kernel=use_kernel, mesh=mesh,
                             quant_fused=quant_fused,
                             corpus=corpus, batch_key=batch_key,
-                            schedule=schedule, slab=slab, plan=plan)
+                            slab=slab, plan=plan)
     if slab is not None or plan is not None:
         raise ValueError("slab/plan are host-tier paging arguments "
                          "(paged specs with cold_placement='host')")
@@ -978,7 +968,7 @@ def engine_round(spec: FlatSpec, state: EngineState, batch=None, *, cfg,
     if cfg.quant_bits > 0 and quant_fused:
         # FAVAS[QNN], codes-in transport: LUQ-encode the transmitted
         # progress per BUCKET on the flat buffers (per-(row, shard) scales)
-        # and keep it as packed codes all the way into the fused pass — the
+        # and keep it as packed codes all the way into the aggregation — the
         # dense (n, Dp) dequantized progress never materializes. Keys fold
         # off k_q under a dedicated tag so the stream can never collide
         # with the paged path's eviction fold (fold_in(k_q, 1)).
@@ -989,54 +979,32 @@ def engine_round(spec: FlatSpec, state: EngineState, batch=None, *, cfg,
         # FAVAS[QNN]: quantize the TRANSMITTED progress in tree space
         # (per-leaf LUQ scale, same per-leaf keys as the seed
         # implementation). Quantization is communication-only (Remark 1):
-        # the fused pass aggregates Q(progress) but resets unselected
-        # clients to their full-precision trained state.
+        # the aggregation sums Q(progress) while unselected clients keep
+        # their full-precision trained state.
         progress = _tree_progress(spec, mesh, trained_tree, state.inits, k_q,
                                   cfg.quant_bits)
 
-    # 4+5. aggregation + selected-client reset: one pass per bucket.
-    # alpha/mask ride to the kernel padded alongside the buffers' client
-    # rows (unit alpha / zero mask => padded rows aggregate exactly nothing
-    # and reset to themselves, i.e. stay zero). sample_selection_indices is
-    # the SAME rng stream as sample_selection (the mask is derived from the
-    # indices), so taking the indices here changes no draw.
+    # 4+5. aggregation, then the selected rows' reset (§13: a scatter, or
+    # a masked pass on small stacks). alpha/mask ride to the kernel padded
+    # alongside the buffers' client rows (unit alpha / zero mask => padded
+    # rows aggregate exactly nothing and are never selected, i.e. stay
+    # zero). sample_selection_indices is the SAME rng stream as
+    # sample_selection (the mask is derived from the indices), so taking
+    # the indices here changes no draw.
     sel_idx, m = sampler.sample_selection_indices(k_sel, n, s)  # (s,), (n,)
     alpha_p = pad_client_vec(spec, alpha, 1.0)
     m_p = pad_client_vec(spec, m, 0.0)
-    server_new, clients_new, inits_new = [], [], []
-    if schedule == "streamed":
-        # §13: single-sweep aggregation, then ONE reset writing the new
-        # server row into the s selected rows of the donated trained/init
-        # buffers (scatter, or a masked pass on small stacks)
-        for b in range(spec.n_buckets):
-            server_new.append(stream_bucket_update(
-                spec, b, state.server[b], trained[b], state.inits[b],
-                alpha_p, m_p, float(s), progress_b=progress[b],
-                progress_codes_b=progress_codes[b],
-                progress_bits=cfg.quant_bits, n_logical=n, mesh=mesh,
-                use_kernel=use_kernel))
-        clients_new = _streamed_reset(spec, mesh, trained, sel_idx,
-                                      server_new)
-        inits_new = _streamed_reset(spec, mesh, state.inits, sel_idx,
-                                    server_new)
-    else:
-        for b in range(spec.n_buckets):
-            srv, cli, ini = fused_bucket_update(
-                spec, b, state.server[b], trained[b], state.inits[b],
-                alpha_p, m_p, float(s), progress_b=progress[b],
-                progress_codes_b=progress_codes[b],
-                progress_bits=cfg.quant_bits, n_logical=n, mesh=mesh,
-                use_kernel=use_kernel)
-            server_new.append(srv)
-            clients_new.append(cli)
-            inits_new.append(ini)
+    server_new, clients_new, inits_new = aggregate_and_reset(
+        spec, state.server, trained, state.inits, alpha_p, m_p, sel_idx, s,
+        progress=progress, progress_codes=progress_codes,
+        progress_bits=cfg.quant_bits, n_logical=n, mesh=mesh,
+        use_kernel=use_kernel)
 
     counters_new = jnp.where(m > 0, 0, new_counters).astype(jnp.int32)
     stale_new = jnp.where(m > 0, 0, state.stale + 1).astype(jnp.int32)
 
-    new_state = EngineState(server=tuple(server_new),
-                            clients=tuple(clients_new),
-                            inits=tuple(inits_new),
+    new_state = EngineState(server=server_new, clients=clients_new,
+                            inits=inits_new,
                             counters=counters_new, stale=stale_new,
                             key=key, t=state.t + 1)
     total_live = jnp.sum(live)
@@ -1060,14 +1028,14 @@ def _paged_round(spec: FlatSpec, state: EngineState, batch, *, cfg,
                  det_alpha: Optional[jnp.ndarray] = None,
                  use_kernel: Optional[bool] = None, mesh=None,
                  quant_fused: bool = False, corpus=None, batch_key=None,
-                 schedule: str = "streamed", slab=None, plan=None):
+                 slab=None, plan=None):
     """One FAVAS round on a paged (hot/cold) spec — docs/architecture.md §9.
 
     Control flow inverts relative to the dense body: Gumbel top-s selection
     runs FIRST, then the hot working set is rebuilt (promote selected cold
     clients by gather+dequant, evict the stalest hot rows by requant+
-    scatter-back), and only the ``s_max`` hot rows see local SGD and the
-    fused aggregation+reset. Cold clients are frozen — their parameters,
+    scatter-back), and only the ``s_max`` hot rows see local SGD, the
+    aggregation and the reset. Cold clients are frozen — their parameters,
     counters and progress do not move until promotion, which is exactly the
     dense semantics for never-selected clients once ``s_max`` covers every
     client touched between two selections of any given id.
@@ -1237,46 +1205,24 @@ def _paged_round(spec: FlatSpec, state: EngineState, batch, *, cfg,
         progress = _tree_progress(spec, mesh, trained_tree, inits_hot, k_q,
                                   cfg.quant_bits)
 
-    # 8. aggregation + selected-client reset over the hot stacks
+    # 8. aggregation + selected-client reset over the hot stacks. Every
+    # selected client is hot (engine_init enforces s <= s_max), so m_hot
+    # carries exactly s ones and the nonzero fill value is never consumed.
     alpha_p = pad_client_vec(spec, alpha, 1.0)
     m_p = pad_client_vec(spec, m_hot, 0.0)
-    server_new, clients_new, inits_new = [], [], []
-    if schedule == "streamed":
-        # §13: every selected client is hot (engine_init enforces
-        # s <= s_max), so m_hot carries exactly s ones and the nonzero
-        # fill value is never consumed. The reset replaces the second
-        # sweep.
-        sel_pos = jnp.nonzero(m_hot > 0, size=s, fill_value=0)[0]
-        for b in range(spec.n_buckets):
-            server_new.append(stream_bucket_update(
-                spec, b, state.server[b], trained[b], inits_hot[b],
-                alpha_p, m_p, float(s), progress_b=progress[b],
-                progress_codes_b=progress_codes[b],
-                progress_bits=cfg.quant_bits, n_logical=s_hot, mesh=mesh,
-                use_kernel=use_kernel))
-        clients_new = _streamed_reset(spec, mesh, trained, sel_pos,
-                                      server_new)
-        inits_new = _streamed_reset(spec, mesh, inits_hot, sel_pos,
-                                    server_new)
-    else:
-        for b in range(spec.n_buckets):
-            srv, cli, ini = fused_bucket_update(
-                spec, b, state.server[b], trained[b], inits_hot[b], alpha_p,
-                m_p, float(s), progress_b=progress[b],
-                progress_codes_b=progress_codes[b],
-                progress_bits=cfg.quant_bits, n_logical=s_hot,
-                mesh=mesh, use_kernel=use_kernel)
-            server_new.append(srv)
-            clients_new.append(cli)
-            inits_new.append(ini)
+    sel_pos = jnp.nonzero(m_hot > 0, size=s, fill_value=0)[0]
+    server_new, clients_new, inits_new = aggregate_and_reset(
+        spec, state.server, trained, inits_hot, alpha_p, m_p, sel_pos, s,
+        progress=progress, progress_codes=progress_codes,
+        progress_bits=cfg.quant_bits, n_logical=s_hot, mesh=mesh,
+        use_kernel=use_kernel)
 
     # 9. scatter the hot counter updates back into the full-n view
     counters_new = state.counters.at[members].set(
         jnp.where(m_hot > 0, 0, q1).astype(jnp.int32))
 
-    new_state = EngineState(server=tuple(server_new),
-                            clients=tuple(clients_new),
-                            inits=tuple(inits_new),
+    new_state = EngineState(server=server_new, clients=clients_new,
+                            inits=inits_new,
                             counters=counters_new, stale=stale_new,
                             key=key, t=state.t + 1,
                             hot_ids=members,
@@ -1362,7 +1308,6 @@ def engine_multi_round(spec: FlatSpec, state: EngineState, batches=None, *,
                        use_kernel: Optional[bool] = None, mesh=None,
                        quant_fused: bool = False,
                        corpus=None, n_rounds: Optional[int] = None,
-                       schedule: str = "streamed",
                        slab=None, plans=None):
     """A whole chunk of FAVAS rounds as ONE ``jax.lax.scan`` — the
     "superstep" (docs/architecture.md §7). Pure; jit/pjit this and donate
@@ -1428,16 +1373,14 @@ def engine_multi_round(spec: FlatSpec, state: EngineState, batches=None, *,
                     lambdas=lambdas, det_alpha=det_alpha,
                     use_kernel=use_kernel, mesh=mesh,
                     quant_fused=quant_fused, corpus=corpus,
-                    batch_key=k_batch, schedule=schedule,
-                    slab=st[1], plan=plan)
+                    batch_key=k_batch, slab=st[1], plan=plan)
                 return (st1, sl), met
             st = dataclasses.replace(st, key=key)
             return engine_round(spec, st, None, cfg=cfg, loss_fn=loss_fn,
                                 lambdas=lambdas, det_alpha=det_alpha,
                                 use_kernel=use_kernel, mesh=mesh,
                                 quant_fused=quant_fused,
-                                corpus=corpus, batch_key=k_batch,
-                                schedule=schedule)
+                                corpus=corpus, batch_key=k_batch)
         if host_cold:
             (st1, sl1), metrics = jax.lax.scan(body_c, (state, slab), plans,
                                                length=n_rounds)
@@ -1452,7 +1395,6 @@ def engine_multi_round(spec: FlatSpec, state: EngineState, batches=None, *,
                                         det_alpha=det_alpha,
                                         use_kernel=use_kernel, mesh=mesh,
                                         quant_fused=quant_fused,
-                                        schedule=schedule,
                                         slab=carry[1], plan=plan)
             return (st1, sl), met
         (st1, sl1), metrics = jax.lax.scan(body_h, (state, slab),
@@ -1463,7 +1405,7 @@ def engine_multi_round(spec: FlatSpec, state: EngineState, batches=None, *,
         return engine_round(spec, st, batch, cfg=cfg, loss_fn=loss_fn,
                             lambdas=lambdas, det_alpha=det_alpha,
                             use_kernel=use_kernel, mesh=mesh,
-                            quant_fused=quant_fused, schedule=schedule)
+                            quant_fused=quant_fused)
     return jax.lax.scan(body, state, batches)
 
 
@@ -1543,8 +1485,7 @@ class RoundEngine:
                  client_tile: int = CLIENT_TILE, mesh=None,
                  residency: str = "dense", s_max: Optional[int] = None,
                  cold_bits: int = 0, quant_fused: bool = False,
-                 cold_placement: str = "device",
-                 schedule: str = "streamed"):
+                 cold_placement: str = "device"):
         from repro.core.favas import client_lambdas  # cycle-free at call time
         self.cfg = cfg
         self.mesh = mesh
@@ -1560,10 +1501,9 @@ class RoundEngine:
         self.det_alpha = None if det_alpha is None else jnp.asarray(det_alpha)
         self.use_kernel = use_kernel
         self.quant_fused = quant_fused
-        self.schedule = schedule
-        # how the streamed round's reset writes each bucket's client and
-        # init stacks (reset_path); fixed per compile, empty on two_sweep
-        self.reset_paths = () if schedule == "two_sweep" else tuple(
+        # how the round's reset writes each bucket's client and init
+        # stacks (reset_path); fixed per compile
+        self.reset_paths = tuple(
             reset_path(self.spec.stacked_rows, cfg.s_selected, dt)
             for dt in self.spec.bucket_dtypes)
         self._round = jax.jit(
@@ -1571,16 +1511,14 @@ class RoundEngine:
                               loss_fn=self.loss_fn, lambdas=self.lambdas,
                               det_alpha=self.det_alpha,
                               use_kernel=self.use_kernel, mesh=self.mesh,
-                              quant_fused=self.quant_fused,
-                              schedule=self.schedule),
+                              quant_fused=self.quant_fused),
             donate_argnums=(0,))
         self._multi = jax.jit(
             functools.partial(engine_multi_round, self.spec, cfg=self.cfg,
                               loss_fn=self.loss_fn, lambdas=self.lambdas,
                               det_alpha=self.det_alpha,
                               use_kernel=self.use_kernel, mesh=self.mesh,
-                              quant_fused=self.quant_fused,
-                              schedule=self.schedule),
+                              quant_fused=self.quant_fused),
             donate_argnums=(0,))
         # device data plane: the corpus rides as a pytree ARGUMENT (not a
         # closure) so its buffers are shared inputs, never baked into the
@@ -1590,8 +1528,7 @@ class RoundEngine:
                               loss_fn=self.loss_fn, lambdas=self.lambdas,
                               det_alpha=self.det_alpha,
                               use_kernel=self.use_kernel, mesh=self.mesh,
-                              quant_fused=self.quant_fused,
-                              schedule=self.schedule),
+                              quant_fused=self.quant_fused),
             static_argnames=("n_rounds",), donate_argnums=(0,))
         # host-placed cold tier (docs §13): the slab rides positionally so
         # it can be donated alongside the state; state.cold is None inside
@@ -1600,8 +1537,7 @@ class RoundEngine:
             common = dict(cfg=self.cfg, loss_fn=self.loss_fn,
                           lambdas=self.lambdas, det_alpha=self.det_alpha,
                           use_kernel=self.use_kernel, mesh=self.mesh,
-                          quant_fused=self.quant_fused,
-                          schedule=self.schedule)
+                          quant_fused=self.quant_fused)
             spec = self.spec
 
             def _rh(state, batch, slab, plan):

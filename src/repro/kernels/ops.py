@@ -12,8 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.favas_agg import (favas_agg_pallas, favas_fused_pallas,
-                                     favas_stream_pallas)
+from repro.kernels.favas_agg import favas_stream_pallas
 from repro.kernels.luq import (luq_decode_pallas, luq_encode_pallas,
                                luq_pallas)
 
@@ -22,12 +21,15 @@ def _is_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def favas_fused_flat(server, clients, inits, alpha, mask, s: float,
-                     *, progress=None, progress_codes=None,
-                     progress_bits: int = 0, progress_shards: int = 1,
-                     client_tile=None, n_logical=None, use_kernel=None):
-    """Fused full-round aggregation + reset over flat buffers; see
-    kernels/favas_agg.py. Returns (server_new, clients_new, inits_new).
+def favas_stream_flat(server, clients, inits, alpha, mask, s: float,
+                      *, progress=None, progress_codes=None,
+                      progress_bits: int = 0, progress_shards: int = 1,
+                      client_tile=None, n_logical=None, use_kernel=None):
+    """FAVAS aggregation over flat buffers (docs §13); see
+    kernels/favas_agg.py. Returns ONLY the (D,) new server vector: the
+    caller writes it into the selected rows of the donated state buffers
+    (``core.round_engine.aggregate_and_reset``), so unselected rows are
+    never rewritten.
 
     Args:
       server: (D,) flat server vector; clients / inits: (n, D) stacks.
@@ -37,8 +39,7 @@ def favas_fused_flat(server, clients, inits, alpha, mask, s: float,
       s: |S_t|; the aggregation divides by ``s + 1``.
       progress: optional explicit (n, D) transmitted progress (e.g. the
         LUQ-quantized client deltas); None means ``clients - inits``,
-        computed inside. Resets always use full-precision ``clients`` —
-        quantization is communication-only (paper Remark 1).
+        computed inside.
       progress_codes: the CODES-IN variant of ``progress`` (mutually
         exclusive with it): a ``{"codes": (n, D*bits/8) uint8, "scale":
         (n, shards) f32}`` encoding from ``cold_requant_rows``. The kernel
@@ -51,63 +52,18 @@ def favas_fused_flat(server, clients, inits, alpha, mask, s: float,
       client_tile: client-axis tile for the kernel path (the jnp oracle is
         shape-agnostic and ignores it).
       n_logical: real client rows when the buffers carry client-tile
-        padding; the oracle path computes on the logical rows and
-        re-attaches the padding as exact zeros, so reducing over a padded
-        row count never reorders the fp32 client sum (keeps the engine
-        bit-identical to ``favas_round_reference`` at any n).
+        padding; the oracle path reduces over the logical rows only, so a
+        padded row count never reorders the fp32 client sum (keeps the
+        engine bit-identical to ``favas_round_reference`` at any n).
       use_kernel: None (auto) picks the Pallas kernel on TPU and the jnp
         oracle on CPU (interpret mode is a validation tool, not a fast
         path); True forces the kernel (interpret off-TPU), False forces the
         oracle.
 
     On a device mesh, call this through
-    ``core.round_engine.fused_bucket_update`` — it wraps the kernel path in
-    ``shard_map`` over per-shard flat slices and pins the oracle path's
-    output shardings, so sharded buckets never gather."""
-    if progress is not None and progress_codes is not None:
-        raise ValueError("progress and progress_codes are mutually exclusive")
-    if use_kernel is None:
-        use_kernel = _is_tpu()
-    if use_kernel:
-        return favas_fused_pallas(server, clients, inits, alpha, mask, s,
-                                  progress=progress,
-                                  progress_codes=progress_codes,
-                                  progress_bits=progress_bits,
-                                  progress_shards=progress_shards,
-                                  client_tile=client_tile,
-                                  interpret=not _is_tpu())
-    if progress_codes is not None:
-        # oracle: decode to dense f32 and run the reference — decode is
-        # row-elementwise, so slice-then-decode == decode-then-slice and
-        # the n_logical handling below stays exact
-        from repro.core.paging import luq_decode_rows   # lazy: no cycle
-        progress = luq_decode_rows(progress_codes, progress_bits,
-                                   jnp.float32, shards=progress_shards)
-    rows = clients.shape[0]
-    nl = rows if n_logical is None else n_logical
-    if nl < rows:
-        srv, cli, ini = ref.favas_fused_ref(
-            server, clients[:nl], inits[:nl], alpha[:nl], mask[:nl], s,
-            progress=None if progress is None else progress[:nl])
-        # padded rows are zero with zero mask: their reset is exactly zero
-        rpad = ((0, rows - nl), (0, 0))
-        return srv, jnp.pad(cli, rpad), jnp.pad(ini, rpad)
-    return ref.favas_fused_ref(server, clients, inits, alpha, mask, s,
-                               progress=progress)
-
-
-def favas_stream_flat(server, clients, inits, alpha, mask, s: float,
-                      *, progress=None, progress_codes=None,
-                      progress_bits: int = 0, progress_shards: int = 1,
-                      client_tile=None, n_logical=None, use_kernel=None):
-    """Aggregation-only half of the STREAMED round schedule (docs §13):
-    the ``favas_fused_flat`` contract, returning ONLY the (D,) new server
-    vector. The caller applies the selected-client reset as a churn-
-    bounded scatter of this row into the donated state buffers
-    (``core.round_engine.stream_bucket_update``), so unselected rows are
-    never rewritten. Same ``use_kernel`` dispatch and the same fp32
-    expressions as the fused path — the server it returns is bit-identical
-    to ``favas_fused_flat``'s per dispatch path."""
+    ``core.round_engine.stream_bucket_update`` — it wraps the kernel path
+    in ``shard_map`` over per-shard flat slices and pins the oracle path's
+    output sharding, so sharded buckets never gather."""
     if progress is not None and progress_codes is not None:
         raise ValueError("progress and progress_codes are mutually exclusive")
     if use_kernel is None:
@@ -121,6 +77,8 @@ def favas_stream_flat(server, clients, inits, alpha, mask, s: float,
                                    client_tile=client_tile,
                                    interpret=not _is_tpu())
     if progress_codes is not None:
+        # oracle: decode to dense f32 and run the reference — decode is
+        # row-elementwise, so slice-then-decode == decode-then-slice
         from repro.core.paging import luq_decode_rows   # lazy: no cycle
         progress = luq_decode_rows(progress_codes, progress_bits,
                                    jnp.float32, shards=progress_shards)
@@ -135,29 +93,6 @@ def favas_stream_flat(server, clients, inits, alpha, mask, s: float,
                                 progress=progress)
 
 
-def favas_aggregate_flat(server, clients, inits, alpha, mask, s: float,
-                         *, client_tile=None, use_kernel: bool = True):
-    """Flat-buffer FAVAS aggregation; see kernels/favas_agg.py."""
-    if use_kernel:
-        return favas_agg_pallas(server, clients, inits, alpha, mask, s,
-                                client_tile=client_tile,
-                                interpret=not _is_tpu())
-    return ref.favas_agg_ref(server, clients, inits, alpha, mask, s)
-
-
-def favas_aggregate_tree(server_tree, clients_tree, inits_tree, alpha, mask,
-                         s: float, *, use_kernel: bool = True):
-    """Leafwise fused aggregation over parameter pytrees (leaves flattened
-    to (n, D) / (D,) buffers)."""
-    def one(w, C, I):
-        D = w.size
-        out = favas_aggregate_flat(w.reshape(-1), C.reshape(C.shape[0], -1),
-                                   I.reshape(I.shape[0], -1), alpha, mask, s,
-                                   use_kernel=use_kernel)
-        return out.reshape(w.shape)
-    return jax.tree_util.tree_map(one, server_tree, clients_tree, inits_tree)
-
-
 def cold_requant_rows(x, bits: int, key, *, shards: int = 1,
                       use_kernel=None):
     """Paged-engine EVICTION path: LUQ-encode (rows, D) hot rows into
@@ -166,7 +101,7 @@ def cold_requant_rows(x, bits: int, key, *, shards: int = 1,
     prune/round as ``luq_pallas``/``luq_ref``, emitting codes instead of
     dequantized floats).
 
-    ``use_kernel`` follows the ``favas_fused_flat`` dispatch contract:
+    ``use_kernel`` follows the ``favas_stream_flat`` dispatch contract:
     None picks the code-emitting Pallas kernel (``kernels.luq.
     luq_encode_pallas``) on TPU and the jnp oracle elsewhere; True forces
     the kernel (interpret mode off-TPU — a validation tool, not a fast

@@ -7,25 +7,13 @@ import jax
 import jax.numpy as jnp
 
 
-def favas_agg_ref(server, clients, inits, alpha, mask, s: float):
-    """Fused FAVAS server aggregation over flattened parameter buffers.
-
-    server: (D,), clients/inits: (n, D), alpha/mask: (n,).
-    out = (server + sum_i mask_i * (init_i + (client_i - init_i)/alpha_i)) / (s+1)
-    """
-    a = alpha[:, None].astype(jnp.float32)
-    m = mask[:, None].astype(jnp.float32)
-    msg = inits.astype(jnp.float32) + (clients.astype(jnp.float32)
-                                       - inits.astype(jnp.float32)) / a
-    total = jnp.sum(m * msg, axis=0)
-    return ((server.astype(jnp.float32) + total) / (s + 1.0)).astype(server.dtype)
-
-
 def favas_fused_ref(server, clients, inits, alpha, mask, s: float,
                     *, progress=None):
-    """Full-round fused oracle: aggregation (line 10) + selected-client reset
-    (lines 11–12) over flat buffers. Mirrors ``favas_agg._fused_kernel``
-    expression-for-expression, so kernel parity holds to 1 fp32 ULP.
+    """Whole-round oracle: aggregation (line 10) + selected-client reset
+    (lines 11–12) over flat buffers, the reset as the blend
+    ``m*s_new + (1-m)*x``. Tests hold the engine's streamed step
+    (``core.round_engine.aggregate_and_reset``) to it; nothing in the
+    program calls it.
 
     server: (D,), clients/inits: (n, D), alpha/mask: (n,). ``progress``:
     optional explicit (quantized) transmitted progress; None means
@@ -47,12 +35,12 @@ def favas_fused_ref(server, clients, inits, alpha, mask, s: float,
 
 def favas_stream_ref(server, clients, inits, alpha, mask, s: float,
                      *, progress=None):
-    """Aggregation-only oracle of the STREAMED schedule (docs §13): the
-    exact ``favas_fused_ref`` server expressions, emitting ONLY the new
-    server row. The selected-client reset happens outside the kernel as a
-    churn-bounded scatter of this row into the s selected positions.
+    """Oracle of ``favas_agg.favas_stream_pallas`` (docs §13): the exact
+    ``favas_fused_ref`` server expressions, emitting ONLY the new server
+    row. The selected-client reset happens outside the kernel, as a write
+    of this row into the s selected positions.
 
-    Bit-exactness with the fused reset: ``mask`` is exactly the 0/1
+    Bit-exactness with the blended reset: ``mask`` is exactly the 0/1
     indicator of the selected index set (Gumbel top-s), so for every
     unselected row ``m*s_new + (1-m)*x == x`` to the bit (the f32
     round-trip of a finite value is identity for f32/bf16 states) and for

@@ -180,12 +180,12 @@ def pass_through_copies(hlo_text: str, *, rows: int, min_cols: int = 128,
     entry outputs of shape ``(rows, >=min_cols, ...)`` in a full-precision
     dtype whose defining op REWRITES the whole buffer.
 
-    The streamed schedule's contract is that the donated client/init
+    The round's contract on large stacks is that the donated client/init
     stacks are only ever touched by churn-bounded in-place updates
     (scatter / dynamic-update-slice on the aliased input), so unselected
-    rows are never rewritten — under the two-sweep schedule the same
-    outputs are full ``(n, D)`` elementwise fusions (the ``m*s_new +
-    (1-m)*x`` blend), ~1 extra read + 1 extra write per resident byte.
+    rows are never rewritten; a full ``(n, D)`` elementwise fusion there
+    (such as the blend ``m*s_new + (1-m)*x``) costs ~1 extra read + 1
+    extra write per resident byte.
     Returns ``(output_name, defining_opcode, dims)`` per violation; a
     compiled streamed round whose stacks take the scatter reset must
     return ``[]``, and one whose stacks are small enough for the masked
@@ -247,21 +247,6 @@ def pass_through_copies(hlo_text: str, *, rows: int, min_cols: int = 128,
         if op not in _IN_PLACE_OPS:
             out.append((name, op, d))
     return out
-
-
-def round_traffic_report(compiled, *, rows: int, min_cols: int = 128) -> Dict:
-    """HBM bytes-accessed-per-round audit of a compiled round executable:
-    total "bytes accessed" from ``compiled.cost_analysis()`` (normalized —
-    the ONE accessor, per ROADMAP) plus the :func:`pass_through_copies`
-    write census. The streamed-vs-two-sweep traffic-reduction gate in
-    tests/test_streaming.py and ``benchmarks.streaming_bench`` read this."""
-    from repro.launch.dryrun import normalize_cost_analysis
-    cost = normalize_cost_analysis(compiled.cost_analysis())
-    return {
-        "bytes_accessed": float(cost.get("bytes accessed", 0.0) or 0.0),
-        "pass_through_copies": pass_through_copies(
-            compiled.as_text(), rows=rows, min_cols=min_cols),
-    }
 
 
 def parse_hlo_collectives(hlo_text: str, *, bf16_dot_comms: bool = False) -> Dict:

@@ -2,8 +2,8 @@
 (docs/architecture.md §11).
 
 This is the simulated-clock round loop of ``core/fl_sim.py`` re-expressed
-as an event-driven server, with the engine's fused flat-buffer update
-(``round_engine.fused_bucket_update``) as the aggregation core. Protocol,
+as an event-driven server, with the engine's flat-buffer round step
+(``round_engine.aggregate_and_reset``) as the aggregation core. Protocol,
 per round ``r`` (cadence ``round_dur``):
 
 1. ``tick``  server -> every client: carry the round index and a
@@ -364,14 +364,11 @@ class FavasAsyncServer(Actor):
                                for c in admitted})
         alpha_p = round_engine.pad_client_vec(self.spec, alpha, 1.0)
         mask_p = round_engine.pad_client_vec(self.spec, mask, 0.0)
-        out = [round_engine.fused_bucket_update(
-                   self.spec, b, self.srv_f[b], jax.numpy.asarray(cli_f[b]),
-                   self.ini_f[b], alpha_p, mask_p, float(len(admitted)),
-                   n_logical=n)
-               for b in range(self.spec.n_buckets)]
-        self.srv_f = tuple(o[0] for o in out)
-        self.cli_f = tuple(o[1] for o in out)
-        self.ini_f = tuple(o[2] for o in out)
+        sel_idx = jax.numpy.asarray([self._row[c] for c in admitted],
+                                    jax.numpy.int32)
+        self.srv_f, self.cli_f, self.ini_f = round_engine.aggregate_and_reset(
+            self.spec, self.srv_f, tuple(map(jax.numpy.asarray, cli_f)),
+            self.ini_f, alpha_p, mask_p, sel_idx, len(admitted), n_logical=n)
 
     def _shutdown(self, api: TransportAPI) -> None:
         self._record(self.cfg.rounds)

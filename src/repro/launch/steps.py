@@ -239,7 +239,7 @@ def build_train_step(arch: str, mesh, fcfg: Optional[FavasConfig] = None,
 
     def step(state, batch):
         # use_agg_kernel=False keeps the jnp oracle under pjit (XLA fuses the
-        # flat-buffer expression); True forces the Pallas fused kernel.
+        # flat-buffer expression); True forces the Pallas kernel.
         if data_plane == "device":
             # batch IS the resident corpus; minibatches are sampled in-scan
             return favas_multi_round(state, corpus=batch,

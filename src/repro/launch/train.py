@@ -4,11 +4,12 @@ Runs on whatever devices exist: a 1-device CPU box (reduced configs, smoke/
 example use) or the production mesh (full configs). One train step = one
 FAVAS server round over the resident clients, driven by the flat-buffer
 ``core.round_engine.RoundEngine``: parameters live in contiguous flat
-buffers across rounds, the jitted round donates them, and the fused
-aggregation+reset runs as one pass (Pallas kernel on TPU, jnp oracle on
-CPU; override with --use-kernel). With --mesh the engine is sharded: flat
-buffers stay partitioned over the "model" mesh axis end-to-end
-(docs/architecture.md §6) and the round never gathers them.
+buffers across rounds, the jitted round donates them, and the aggregation
+runs as one pass (Pallas kernel on TPU, jnp oracle on CPU; override with
+--use-kernel) before the selected clients' rows are reset. With --mesh the
+engine is sharded: flat buffers stay partitioned over the "model" mesh
+axis end-to-end (docs/architecture.md §6) and the round never gathers
+them.
 
 The host loop is pipelined (docs/architecture.md §7): with
 ``--rounds-per-step T`` every chunk of T rounds runs as ONE on-device
@@ -64,7 +65,7 @@ def build_cli():
     ap.add_argument("--quant-fused", action="store_true",
                     help="with --quant-bits > 0: transport the FAVAS[QNN] "
                          "progress as bit-packed LUQ codes + per-(row, "
-                         "shard) scales all the way into the fused round "
+                         "shard) scales all the way into the aggregation "
                          "(dequantized per VMEM tile, no dense (n, D) f32 "
                          "progress buffer — docs/architecture.md §10); "
                          "default quantizes per leaf and hands the kernel "
@@ -113,7 +114,7 @@ def build_cli():
                          "bit-exact with device placement")
     ap.add_argument("--use-kernel", default="auto",
                     choices=["auto", "on", "off"],
-                    help="fused Pallas aggregation kernel: auto = TPU only "
+                    help="Pallas aggregation kernel: auto = TPU only "
                          "(CPU gets the jnp oracle), on = force (interpret "
                          "mode off-TPU), off = always the oracle")
     ap.add_argument("--mesh", default="none",

@@ -8,16 +8,16 @@ compiled program holds the Mosaic kernel (``tpu_custom_call``). Nothing
 runs, so nothing here is a result or a time. Each kernel also carries its
 ``pallas_call`` name, the name a profiler trace shows for it.
 
-Shapes: the streamed and fused rounds at the bucket width of the Qwen3-4B
+Shapes: the streamed aggregation at the bucket width of the Qwen3-4B
 configuration ``chip_smoke.py`` trains (depth 1, a quarter of the
 vocabulary), from ``jax.eval_shape`` on its ``make_flat_spec``; the
-streamed kernel's wide lane blocks at the bucket of the benchmark's
-Mamba2-1.3B fan-in cell (8 rows, 52,066,304 f32 lanes). At n=64 the
-client stacks of that width need about 95 GB, six times the chip's HBM,
-which the compiler refuses; the n=64 cases compile a sixteenth of the width
-instead — the block shapes do not depend on D once it spans a block. The
-LUQ codec and the codes-in stream compile at D = 2^20 for bits {2, 4, 8} x
-shards {1, 4}.
+streamed kernel's wide lane blocks, and its dense-``progress`` variant, at
+the bucket of the benchmark's Mamba2-1.3B fan-in cell (8 rows, 52,066,304
+f32 lanes). At n=64 the client stacks of the smoke's width need about
+95 GB, six times the chip's HBM, which the compiler refuses; the n=64
+cases compile a sixteenth of the width instead — the block shapes do not
+depend on D once it spans a block. The LUQ codec and the codes-in stream
+compile at D = 2^20 for bits {2, 4, 8} x shards {1, 4}.
 
 The topology is described inside a module fixture (never at import), so
 workers that do not run this file never load the TPU library.
@@ -34,7 +34,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import favas_agg
 from repro.kernels.favas_agg import (STREAM_VMEM_BUDGET, TILE,
-                                     favas_agg_pallas, favas_fused_pallas,
                                      favas_stream_pallas,
                                      stream_block_vmem_bytes, stream_width)
 from repro.kernels.luq import luq_decode_pallas, luq_encode_pallas, luq_pallas
@@ -111,25 +110,35 @@ def _width(smoke_width, n):
     return smoke_width // 16 // TILE * TILE
 
 
+def _stream(progress):
+    """The streamed kernel, plain or with a dense transmitted ``progress``
+    (FAVAS[QNN] with tree-space LUQ, the default transport)."""
+    if progress:
+        return (lambda w, c, i, p, a, m: favas_stream_pallas(
+            w, c, i, a, m, 1.0, progress=p))
+    return lambda w, c, i, a, m: favas_stream_pallas(w, c, i, a, m, 1.0)
+
+
 @pytest.mark.parametrize("n", [2, 64])
-@pytest.mark.parametrize("kernel,name",
-                         [(favas_stream_pallas, "favas_stream"),
-                          (favas_fused_pallas, "favas_fused")],
-                         ids=["stream", "fused"])
-def test_round_kernel_compiles(one_chip, smoke_width, kernel, name, n):
-    args = _round_args(one_chip, n, _width(smoke_width, n))
-    _compile(name, lambda *a: kernel(*a, 1.0), *args)
+@pytest.mark.parametrize("progress", [False, True],
+                         ids=["stream", "progress"])
+def test_round_kernel_compiles(one_chip, smoke_width, progress, n):
+    w, c, i, a, m = _round_args(one_chip, n, _width(smoke_width, n))
+    args = (w, c, i, c, a, m) if progress else (w, c, i, a, m)
+    _compile("favas_stream", _stream(progress), *args)
 
 
 @pytest.mark.parametrize("n", [2, 64])
 def test_round_kernel_bf16_compiles(one_chip, smoke_width, n):
     args = _round_args(one_chip, n, _width(smoke_width, n), jnp.bfloat16)
-    _compile("favas_stream", lambda *a: favas_stream_pallas(*a, 1.0), *args)
+    _compile("favas_stream", _stream(False), *args)
 
 
-def test_agg_kernel_compiles(one_chip):
-    _compile("favas_agg", lambda *a: favas_agg_pallas(*a, 1.0),
-             *_round_args(one_chip, 64, CODEC_D))
+def test_progress_stream_compiles_cell_width(one_chip):
+    """The dense-``progress`` variant, resident at the fan-in cell's
+    bucket: three (8, D) stacks, TILE-wide blocks."""
+    w, c, i, a, m = _round_args(one_chip, 8, CELL_WIDTH)
+    _compile("favas_stream", _stream(True), w, c, i, c, a, m)
 
 
 def test_luq_kernel_compiles(one_chip):

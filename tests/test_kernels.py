@@ -7,14 +7,15 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref, ops
-from repro.kernels.favas_agg import favas_agg_pallas
+from repro.core import round_engine
+from repro.kernels.favas_agg import favas_stream_pallas
 from repro.kernels.luq import luq_pallas
 
 
 @pytest.mark.parametrize("n,D", [(2, 17), (4, 1000), (8, 2048), (16, 4097),
                                  (32, 65536)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_favas_agg_kernel_matches_ref(n, D, dtype):
+def test_favas_stream_kernel_matches_ref(n, D, dtype):
     key = jax.random.PRNGKey(n * 1000 + D)
     ks = jax.random.split(key, 5)
     server = jax.random.normal(ks[0], (D,), dtype)
@@ -23,12 +24,12 @@ def test_favas_agg_kernel_matches_ref(n, D, dtype):
     alpha = jax.random.uniform(ks[3], (n,), minval=1.0, maxval=8.0)
     mask = (jax.random.uniform(ks[4], (n,)) > 0.5).astype(jnp.float32)
     s = float(mask.sum())
-    out_k = favas_agg_pallas(server, clients, inits, alpha, mask, s,
-                             interpret=True)
-    out_r = ref.favas_agg_ref(server, clients, inits, alpha, mask, s)
-    # kernel fuses (mask*init + coef*(client-init)) * 1/(s+1); the ref
-    # divides — identical in f32, but the bf16 OUTPUT cast can differ by
-    # 1 ULP (~2^-8 relative) on either side.
+    out_k = favas_stream_pallas(server, clients, inits, alpha, mask, s,
+                                interpret=True)
+    out_r = ref.favas_stream_ref(server, clients, inits, alpha, mask, s)
+    # the kernel body and the ref are the same expressions, compiled apart
+    # (FMA contraction): identical to an f32 ULP, but the bf16 OUTPUT cast
+    # can then differ by 1 ULP (~2^-8 relative) on either side.
     tol = dict(rtol=2e-6, atol=2e-6) if dtype == jnp.float32 else \
         dict(rtol=8e-3, atol=8e-3)
     np.testing.assert_allclose(np.asarray(out_k, np.float32),
@@ -66,7 +67,8 @@ def test_luq_output_is_on_grid():
 
 
 def test_ops_tree_aggregation_matches_loop():
-    """favas_aggregate_tree == naive python-loop oracle on a small pytree."""
+    """The per-bucket streamed step (kernel path) on a flattened small
+    pytree == naive python-loop oracle, leaf by leaf."""
     key = jax.random.PRNGKey(4)
     n = 4
     tree = {"a": jax.random.normal(key, (8, 6)),
@@ -80,7 +82,13 @@ def test_ops_tree_aggregation_matches_loop():
     alpha = jnp.array([1.0, 2.0, 4.0, 8.0])
     mask = jnp.array([1.0, 0.0, 1.0, 0.0])
     s = 2.0
-    got = ops.favas_aggregate_tree(tree, C, I, alpha, mask, s, use_kernel=True)
+    spec = round_engine.make_flat_spec(tree, n_clients=n)
+    server = round_engine.flatten_tree(spec, tree)
+    srv, cli, ini = round_engine.aggregate_and_reset(
+        spec, server, round_engine.flatten_stacked(spec, C),
+        round_engine.flatten_stacked(spec, I), alpha, mask,
+        jnp.array([0, 2], jnp.int32), s, n_logical=n, use_kernel=True)
+    got = round_engine.unflatten_tree(spec, srv)
 
     def naive(w, Cl, Il):
         acc = np.asarray(w, np.float64).copy()
@@ -95,3 +103,14 @@ def test_ops_tree_aggregation_matches_loop():
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(g), w, rtol=1e-5, atol=1e-5)
+    # the selected clients (rows 0 and 2) now hold the new server, the
+    # others their own state
+    for stack, orig in ((cli, C), (ini, I)):
+        back = round_engine.unflatten_stacked(spec, stack)
+        for b, o, g in zip(jax.tree_util.tree_leaves(back),
+                           jax.tree_util.tree_leaves(orig),
+                           jax.tree_util.tree_leaves(got)):
+            np.testing.assert_array_equal(np.asarray(b)[[0, 2]],
+                                          np.stack([np.asarray(g)] * 2))
+            np.testing.assert_array_equal(np.asarray(b)[[1, 3]],
+                                          np.asarray(o)[[1, 3]])

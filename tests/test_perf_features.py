@@ -75,12 +75,14 @@ def test_fedavg_round_trains():
 
 
 @pytest.mark.slow
-def test_dryrun_one_combo_subprocess():
-    """The 512-device dry-run must succeed end-to-end (cheapest combo)."""
+def test_dryrun_one_combo_subprocess(tmp_path):
+    """The 512-device dry-run must succeed end-to-end (cheapest combo). It
+    writes its report under ``tmp_path``, not over the tracked one."""
     env = dict(os.environ, PYTHONPATH="src")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
-         "recurrentgemma-2b", "--shape", "long_500k", "--mesh", "multi"],
+         "recurrentgemma-2b", "--shape", "long_500k", "--mesh", "multi",
+         "--out", str(tmp_path)],
         capture_output=True, text=True, env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         timeout=600)

@@ -1,8 +1,10 @@
 """Flat-buffer round engine tests:
 
-* parity of the multi-output fused Pallas kernel (interpret mode) against
-  the jnp oracle — bit-for-bit in fp32 for all three outputs across odd D
-  (padding path), n in {1, 4, 64}, and bf16 params;
+* parity of the streamed round step (``round_engine.aggregate_and_reset``
+  on the ``favas_stream`` Pallas kernel, interpret mode) against the
+  whole-round jnp oracle ``favas_fused_ref`` — server, clients and inits,
+  to 1 fp32 ULP across odd D (padding path), n in {1, 4, 64}, and bf16
+  params — and of the kernel's server row against ``favas_stream_ref``;
 * flatten/unflatten round-trips (mixed-dtype buckets included);
 * regression: ``favas_round`` on the engine reproduces the seed's per-leaf
   tree_map implementation (``favas_round_reference``) exactly.
@@ -19,7 +21,7 @@ from repro.core import (FavasConfig, favas_init, favas_round,
                         favas_round_reference, client_lambdas)
 from repro.core import round_engine
 from repro.kernels import ref
-from repro.kernels.favas_agg import favas_fused_pallas
+from repro.kernels.favas_agg import favas_stream_pallas
 from repro.models.model import init_params, loss_fn
 from repro.utils.tree import tree_map, tree_sq_dist
 
@@ -34,32 +36,50 @@ def _fused_inputs(n, D, dtype, seed=0):
     return server, clients, inits, alpha, mask, float(mask.sum())
 
 
+def _stream_step(server, clients, inits, alpha, mask, s, *, progress=None,
+                 use_kernel=True):
+    """The engine's round step on one bucket of raw flat buffers: the
+    selection indices are the rows ``mask`` selects."""
+    n = clients.shape[0]
+    spec = round_engine.make_flat_spec({"w": server}, n_clients=n)
+    sel_idx = jnp.asarray(np.flatnonzero(np.asarray(mask)), jnp.int32)
+    (srv,), (cli,), (ini,) = round_engine.aggregate_and_reset(
+        spec, (server,), (clients,), (inits,), alpha, mask, sel_idx, s,
+        progress=None if progress is None else (progress,), n_logical=n,
+        use_kernel=use_kernel)
+    return srv, cli, ini
+
+
 @pytest.mark.parametrize("n", [1, 4, 64])
 @pytest.mark.parametrize("D", [17, 1000, 2048, 4097])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_fused_kernel_matches_oracle(n, D, dtype):
+def test_stream_step_matches_oracle(n, D, dtype):
     args = _fused_inputs(n, D, dtype, seed=n * 1000 + D)
-    got = favas_fused_pallas(*args, interpret=True)
+    got = _stream_step(*args)
     want = ref.favas_fused_ref(*args)
+    # the kernel body and the oracle are the same jnp expressions, but
+    # XLA compiles them separately (FMA contraction, blocked n-row
+    # reductions), so "bit-for-bit" holds only up to 1 fp32 ULP
+    tol = dict(rtol=2e-7, atol=2e-7) if dtype == jnp.float32 else \
+        dict(rtol=8e-3, atol=8e-3)
     for name, g, w in zip(("server", "clients", "inits"), got, want):
         assert g.dtype == w.dtype and g.shape == w.shape
-        g32 = np.asarray(g, np.float32)
-        w32 = np.asarray(w, np.float32)
-        # the kernel body and the oracle are the same jnp expressions, but
-        # XLA compiles them separately (FMA contraction, blocked n-row
-        # reductions), so "bit-for-bit" holds only up to 1 fp32 ULP
-        tol = dict(rtol=2e-7, atol=2e-7) if dtype == jnp.float32 else \
-            dict(rtol=8e-3, atol=8e-3)
-        np.testing.assert_allclose(g32, w32, err_msg=name, **tol)
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32), err_msg=name,
+                                   **tol)
+    # the kernel alone against its own oracle
+    srv = favas_stream_pallas(*args, interpret=True)
+    np.testing.assert_allclose(np.asarray(srv, np.float32),
+                               np.asarray(ref.favas_stream_ref(*args),
+                                          np.float32), **tol)
 
 
-def test_fused_kernel_zero_selection():
+def test_stream_step_zero_selection():
     """s = 0 (no client selected): server' = server / 1, clients untouched."""
     n, D = 4, 300
     server, clients, inits, alpha, _, _ = _fused_inputs(n, D, jnp.float32, 3)
     mask = jnp.zeros((n,), jnp.float32)
-    srv, cli, ini = favas_fused_pallas(server, clients, inits, alpha, mask,
-                                       0.0, interpret=True)
+    srv, cli, ini = _stream_step(server, clients, inits, alpha, mask, 0.0)
     np.testing.assert_array_equal(np.asarray(srv), np.asarray(server))
     np.testing.assert_array_equal(np.asarray(cli), np.asarray(clients))
     np.testing.assert_array_equal(np.asarray(ini), np.asarray(inits))
@@ -188,14 +208,13 @@ def test_favas_round_matches_reference_impl_quantized():
         assert float(tree_sq_dist(s_new.inits, s_ref.inits)) == 0.0
 
 
-def test_fused_kernel_explicit_progress_matches_oracle():
+def test_stream_step_explicit_progress_matches_oracle():
     """The QNN kernel variant (explicit progress operand) matches the
-    oracle, and the reset outputs keep full-precision clients."""
+    oracle, and the reset keeps full-precision clients."""
     n, D = 4, 3001
     server, clients, inits, alpha, mask, s = _fused_inputs(n, D, jnp.float32, 9)
     prog = jax.random.normal(jax.random.PRNGKey(10), (n, D))
-    got = favas_fused_pallas(server, clients, inits, alpha, mask, s,
-                             progress=prog, interpret=True)
+    got = _stream_step(server, clients, inits, alpha, mask, s, progress=prog)
     want = ref.favas_fused_ref(server, clients, inits, alpha, mask, s,
                                progress=prog)
     for name, g, w in zip(("server", "clients", "inits"), got, want):

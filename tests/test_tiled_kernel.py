@@ -1,12 +1,14 @@
 """The tiled-client-axis test tier (PR 2).
 
-The fused FAVAS round kernel streams (CLIENT_TILE, TILE) client blocks
-through a VMEM scratch accumulator so n scales to thousands. This file
-proves that regime:
+The ``favas_stream`` aggregation kernel streams (CLIENT_TILE, TILE) client
+blocks through a VMEM scratch accumulator so n scales to thousands. This
+file proves that regime:
 
-* parity of the tiled kernels (interpret mode) against the shape-agnostic
-  jnp oracles across n x dtype x progress sweeps, including n not a
-  multiple of CLIENT_TILE and D not a multiple of TILE;
+* parity of the streamed round step (kernel in interpret mode, then the
+  selected rows' reset) against the shape-agnostic whole-round oracle
+  ``favas_fused_ref``, and of the kernel against ``favas_stream_ref``,
+  across n x dtype x progress sweeps, including n not a multiple of
+  CLIENT_TILE and D not a multiple of TILE;
 * a 1-ULP-at-accumulator-scale bound at the production client count
   (n=1024) — the tiled kernel reorders the client reduction (per-block
   partial sums accumulated sequentially), so parity is bounded by ULPs of
@@ -33,9 +35,8 @@ from repro.core import (FavasConfig, favas_init, favas_round,
 from repro.core import round_engine
 from repro.core.quant import luq_quantize as quant_luq
 from repro.kernels import ops, ref
-from repro.kernels.favas_agg import (CLIENT_TILE, TILE, favas_agg_pallas,
-                                     favas_fused_pallas,
-                                     fused_block_vmem_bytes)
+from repro.kernels.favas_agg import (CLIENT_TILE, TILE, favas_stream_pallas,
+                                     stream_tile_vmem_bytes)
 
 
 def _fused_inputs(n, D, dtype, seed=0):
@@ -48,18 +49,31 @@ def _fused_inputs(n, D, dtype, seed=0):
     return server, clients, inits, alpha, mask, float(mask.sum())
 
 
+def _stream_step(server, clients, inits, alpha, mask, s, *, progress=None):
+    """The engine's round step (kernel path) on one bucket of raw flat
+    buffers: the selection indices are the rows ``mask`` selects."""
+    n = clients.shape[0]
+    spec = round_engine.make_flat_spec({"w": server}, n_clients=n)
+    sel_idx = jnp.asarray(np.flatnonzero(np.asarray(mask)), jnp.int32)
+    (srv,), (cli,), (ini,) = round_engine.aggregate_and_reset(
+        spec, (server,), (clients,), (inits,), alpha, mask, sel_idx, s,
+        progress=None if progress is None else (progress,), n_logical=n,
+        use_kernel=True)
+    return srv, cli, ini
+
+
 # ---------------------------------------------------------------------------
 # Tiled kernel parity vs the shape-agnostic oracle
 # ---------------------------------------------------------------------------
 
 # D=2500 is not a multiple of TILE (lane padding path) and spans two lane
 # tiles; n=257/1000 are not multiples of CLIENT_TILE (row padding path);
-# n=64/257/1000 exceed CLIENT_TILE=32 (tiled two-phase path); n=1/7 keep
-# the resident single-sweep path so both dispatches stay covered.
+# n=64/257/1000 exceed CLIENT_TILE=32 (tiled path); n=1/7 keep the
+# resident path so both dispatches stay covered.
 @pytest.mark.parametrize("n", [1, 7, 64, 257, 1000])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("quantized", [False, True])
-def test_fused_tiled_matches_oracle(n, dtype, quantized):
+def test_stream_tiled_matches_oracle(n, dtype, quantized):
     D = 2500
     server, clients, inits, alpha, mask, s = _fused_inputs(
         n, D, dtype, seed=n + 17 * quantized)
@@ -69,8 +83,8 @@ def test_fused_tiled_matches_oracle(n, dtype, quantized):
         progress = ops.luq_quantize(
             (clients - inits).astype(jnp.float32), 4,
             jax.random.PRNGKey(n), use_kernel=False).astype(dtype)
-    got = favas_fused_pallas(server, clients, inits, alpha, mask, s,
-                             progress=progress, interpret=True)
+    got = _stream_step(server, clients, inits, alpha, mask, s,
+                       progress=progress)
     want = ref.favas_fused_ref(server, clients, inits, alpha, mask, s,
                                progress=progress)
     tol = (dict(rtol=1e-6, atol=1e-6) if dtype == jnp.float32
@@ -89,19 +103,19 @@ def test_fused_tiled_matches_oracle(n, dtype, quantized):
 
 
 @pytest.mark.parametrize("n,D", [(64, 4097), (257, 3000)])
-def test_agg_tiled_matches_ref(n, D):
-    """The single-output aggregation kernel's tiled path (one sweep, scratch
-    accumulator + @pl.when epilogue)."""
+def test_stream_tiled_matches_ref(n, D):
+    """The aggregation kernel's tiled path (one sweep, scratch accumulator
+    + @pl.when epilogue) against its own oracle."""
     server, clients, inits, alpha, mask, s = _fused_inputs(n, D, jnp.float32,
                                                            seed=n)
-    out_k = favas_agg_pallas(server, clients, inits, alpha, mask, s,
-                             interpret=True)
-    out_r = ref.favas_agg_ref(server, clients, inits, alpha, mask, s)
+    out_k = favas_stream_pallas(server, clients, inits, alpha, mask, s,
+                                interpret=True)
+    out_r = ref.favas_stream_ref(server, clients, inits, alpha, mask, s)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=1e-6, atol=1e-6)
 
 
-def test_fused_tiled_one_ulp_at_accumulator_scale():
+def test_stream_tiled_one_ulp_at_accumulator_scale():
     """Production client count: the tiled kernel reorders the client-axis
     reduction, so the only daylight vs the oracle is summation order. Bound
     it by 1 fp32 ULP of the accumulator magnitude per lane
@@ -109,8 +123,7 @@ def test_fused_tiled_one_ulp_at_accumulator_scale():
     n, D = 1024, 6144
     server, clients, inits, alpha, mask, s = _fused_inputs(n, D, jnp.float32,
                                                            seed=11)
-    got = favas_fused_pallas(server, clients, inits, alpha, mask, s,
-                             interpret=True)
+    got = _stream_step(server, clients, inits, alpha, mask, s)
     want = ref.favas_fused_ref(server, clients, inits, alpha, mask, s)
     msg = (np.asarray(inits, np.float64)
            + (np.asarray(clients, np.float64) - np.asarray(inits, np.float64))
@@ -122,38 +135,37 @@ def test_fused_tiled_one_ulp_at_accumulator_scale():
     srv_diff = np.abs(np.asarray(got[0], np.float64)
                       - np.asarray(want[0], np.float64))
     assert np.all(srv_diff <= ulp), float((srv_diff / ulp).max())
-    # the reset outputs blend s_new with untouched state, so the same
-    # per-lane bound applies to every row
+    # the reset writes s_new into the selected rows and leaves the others,
+    # so the same per-lane bound applies to every row
     for g, w in zip(got[1:], want[1:]):
         d = np.abs(np.asarray(g, np.float64) - np.asarray(w, np.float64))
         assert np.all(d <= ulp[None, :]), float((d / ulp[None, :]).max())
 
 
-def test_fused_vmem_budget_production_shape():
+def test_stream_tile_vmem_budget_production_shape():
     """Acceptance: n=1024, D=2^20 per-grid-step VMEM <= 2 MiB, asserted from
     the declared block shapes. The tiled footprint must be independent of
     both n and D — that is what lets the engine scale."""
     budget = 2 * 1024 * 1024
-    got = fused_block_vmem_bytes(1024, jnp.float32)
+    got = stream_tile_vmem_bytes(1024, jnp.float32)
     assert got <= budget, got
-    assert fused_block_vmem_bytes(1024, jnp.float32, progress=True) <= budget
+    assert stream_tile_vmem_bytes(1024, jnp.float32, progress=True) <= budget
     # block shapes carry no D term at all, and no n term beyond CLIENT_TILE:
     # n=2^20 clients costs the same VMEM as n=1024 (only HBM grows)
-    assert fused_block_vmem_bytes(1 << 20, jnp.float32) == got
-    # the declared blocks: (1,T) server in/out + 2x(CT,T) rows in/out
-    # + 2x(CT,1) f32 scalars + 2x(1,T) f32 scratch
-    expect = (2 * TILE * 4 + 4 * CLIENT_TILE * TILE * 4
-              + 2 * CLIENT_TILE * 4 + 2 * TILE * 4)
+    assert stream_tile_vmem_bytes(1 << 20, jnp.float32) == got
+    # the declared blocks, two copies each: (1,T) server in and out
+    # + 2x(CT,T) rows in + 2x(CT,1) f32 scalars; + one (1,T) f32 scratch
+    expect = (2 * (2 * TILE * 4 + 2 * CLIENT_TILE * TILE * 4
+                   + 2 * CLIENT_TILE * 4) + TILE * 4)
     assert got == expect
 
 
-def test_fused_tiled_zero_selection():
+def test_stream_tiled_zero_selection():
     """s = 0, n > CLIENT_TILE: server passes through, clients untouched."""
     n, D = CLIENT_TILE * 3 + 5, 300
     server, clients, inits, alpha, _, _ = _fused_inputs(n, D, jnp.float32, 3)
     mask = jnp.zeros((n,), jnp.float32)
-    srv, cli, ini = favas_fused_pallas(server, clients, inits, alpha, mask,
-                                       0.0, interpret=True)
+    srv, cli, ini = _stream_step(server, clients, inits, alpha, mask, 0.0)
     np.testing.assert_array_equal(np.asarray(srv), np.asarray(server))
     np.testing.assert_array_equal(np.asarray(cli), np.asarray(clients))
     np.testing.assert_array_equal(np.asarray(ini), np.asarray(inits))
